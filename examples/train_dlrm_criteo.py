@@ -24,41 +24,36 @@ Two backends:
 
 ~100M params: 12 tables x 2^16 rows x 96-dim = 75.5M embedding, plus
 bottom/top MLPs (kept modest so the CPU run finishes in minutes). The
-production-size config is `--arch dlrm-criteo` in the dry-run.
+proc loop is `repro.train.feed_loop.train_on_feed`; `chip_smoke.py`
+runs the same loop on a TPU at dlrm-criteo's published widths.
 """
 import argparse
-import os
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs.base import DLRMConfig
 from repro.core.controller import InTune
-from repro.data.pipeline import criteo_pipeline, train_feed_pipeline
-from repro.data.simulator import Allocation, MachineSpec
+from repro.data.featurize import RecordSpec
+from repro.data.pipeline import criteo_pipeline
+from repro.data.simulator import MachineSpec
 from repro.data.synthetic import CriteoStream
-from repro.models import dlrm as dlrm_lib
 from repro.train import checkpoint as ckpt
-from repro.train.optim import make_optimizer
-from repro.train.train_step import make_train_step
+from repro.train.feed_loop import DLRMTrainer, train_on_feed
 
 
-def build_model(batch: int):
-    n_sparse, n_dense, rows, dim = 12, 13, 1 << 16, 96
+def build_trainer():
+    n_sparse, rows, dim = 12, 1 << 16, 96
     cfg = DLRMConfig(
-        name="dlrm-100m", n_sparse=n_sparse, n_dense=n_dense,
+        name="dlrm-100m", n_sparse=n_sparse, n_dense=13,
         embed_dim=dim, vocab_sizes=(rows,) * n_sparse,
         bottom_mlp=(512, 256, 96), top_mlp=(1024, 512, 256, 1))
-    params, _ = dlrm_lib.init_params(jax.random.PRNGKey(0), cfg)
-    n_params = sum(int(np.prod(p.shape))
-                   for p in jax.tree_util.tree_leaves(params))
-    print(f"model: {n_params/1e6:.1f}M params")
-    opt = make_optimizer("adagrad", lr=0.02)
-    step_fn = jax.jit(make_train_step(
-        lambda p, b: dlrm_lib.loss_fn(p, cfg, b), opt))
-    return cfg, params, opt, step_fn
+    trainer = DLRMTrainer(cfg, optimizer="adagrad", lr=0.02)
+    print(f"model: {trainer.n_params/1e6:.1f}M params")
+    return trainer
 
 
 def restore_or_init(ckpt_dir, params, opt_state, tuner):
@@ -91,91 +86,29 @@ def save_step(ckpt_dir, i, params, opt_state, tuner):
 
 
 def run_proc(args):
-    """The closed loop: tuned ProcessPipeline feeds the real train step."""
-    from repro.api import FeedBackend, Session
-    from repro.data.device_feed import make_train_feed
-    from repro.data.featurize import (RecordSpec, featurize_block,
-                                      featurize_stage_fns, raw_block)
+    """The closed loop: tuned ProcessPipeline feeds the real train step
+    (the shared loop in repro.train.feed_loop)."""
+    trainer = build_trainer()
+    cfg = trainer.cfg
+    # 4-hot bags pooled from raw lists of up to 8 ids
+    record = RecordSpec(batch=args.batch, n_sparse=cfg.n_sparse,
+                        n_dense=cfg.n_dense, vocab=cfg.vocab_sizes[0])
 
-    cfg, params, opt, step_fn = build_model(args.batch)
-    opt_state = opt.init(params)
-    rec = RecordSpec(batch=args.batch, n_sparse=cfg.n_sparse,
-                     n_dense=cfg.n_dense, vocab=cfg.vocab_sizes[0])
+    def save(i, params, opt_state, tuner):
+        if (args.ckpt_every and (i + 1) % args.ckpt_every == 0) \
+                or i == args.steps - 1:
+            save_step(args.ckpt_dir, i, params, opt_state, tuner)
 
-    # warm up the jit + measure the raw device step time: the pipeline's
-    # CPU budget (train_feed_pipeline cpu_share) is set relative to THIS,
-    # so ingestion can keep up at a sane allocation but not at a bad one
-    warm = {k: jnp.asarray(v) for k, v in featurize_block(
-        raw_block(np.random.RandomState(0), rec), rec).items()}
-    params, opt_state, _ = step_fn(params, opt_state, 0, warm)
-    t0 = time.monotonic()
-    for k in range(3):
-        params, opt_state, _ = step_fn(params, opt_state, k, warm)
-    jax.block_until_ready(params)
-    step_time = (time.monotonic() - t0) / 3
-    print(f"measured device step time: {step_time*1e3:.0f} ms")
-
-    from repro.data.proc_executor import ProcessPipeline
-    spec = train_feed_pipeline(step_time_s=step_time, work="real")
-    # n_cpus=12 bounds how far the tuner's exploration can over-place
-    # workers: on a small host, every extra worker steals silicon from
-    # the trainer itself, so a huge fake machine makes the warmup phase
-    # painfully slow before the agent learns to back off
-    machine = MachineSpec(n_cpus=12, mem_mb=4096)
     # pin_cpus=1 leaves the host's remaining cores (if any) to the
     # trainer process; the tuner's CPU headroom is contention-real
-    pipe = ProcessPipeline(spec, fns=featurize_stage_fns(spec, record=rec),
-                           machine=machine, pin_cpus=1)
-    pipe.set_allocation([1] * len(spec.stages), prefetch_mb=32.0)
-    # timeout: a cold pipeline must push one batch through every stage
-    # serially before anything reaches the sink
-    feed = make_train_feed(pipe, depth=2,
-                           timeout=max(120.0, 60.0 * step_time))
-    # device_step_s: on a shared-core host ingestion steals silicon from
-    # the trainer instead of letting it block, so device_idle_frac is
-    # scored as 1 - device_busy/wall against the uncontended step time
-    backend = FeedBackend(pipe, feed, device_step_s=step_time)
-    # init_alloc: start the exploration walk where the pipe actually
-    # launched (minimal workers), not at heuristic_even — at a feed
-    # boundary the reward is device business, and over-placed workers
-    # steal the trainer's own cores
-    tuner = InTune(spec, machine, seed=0, head="factored",
-                   finetune_ticks=args.finetune_ticks,
-                   init_alloc=Allocation(
-                       np.ones(len(spec.stages), dtype=int),
-                       prefetch_mb=32.0),
-                   # live windows are noisy: visit-penalized incumbent
-                   # tracking + switch hysteresis (see fig_train_feed)
-                   lcb_coef=0.15, switch_margin=0.05)
-    session = Session(backend, tuner)
-
-    start, params, opt_state = restore_or_init(
-        args.ckpt_dir, params, opt_state, tuner)
-    t0 = time.time()
-    losses, idle = [], None
-    try:
-        for i in range(start, args.steps):
-            batch = next(feed)
-            params, opt_state, metrics = step_fn(params, opt_state, i, batch)
-            losses.append(float(metrics["loss"]))
-            if i % args.tune_every == 0:
-                jax.block_until_ready(params)  # close the step window
-                tel = session.step()
-                idle = tel.device_idle_frac
-            if i % 25 == 0:
-                rate = (i - start + 1) * args.batch / (time.time() - t0)
-                print(f"step {i:4d} loss {losses[-1]:.4f} "
-                      f"({rate:,.0f} samples/s) device_idle "
-                      f"{idle if idle is None else round(idle, 3)} "
-                      f"workers {pipe.worker_counts()}")
-            if (args.ckpt_every and (i + 1) % args.ckpt_every == 0) \
-                or i == args.steps - 1:
-                save_step(args.ckpt_dir, i, params, opt_state, tuner)
-    finally:
-        acct = session.close()
-        print(f"feed teardown: {acct}")
-    print(f"final loss {np.mean(losses[-20:]):.4f} "
-          f"(first-20 {np.mean(losses[:20]):.4f}); "
+    run = train_on_feed(
+        trainer, record, steps=args.steps, tune_every=args.tune_every,
+        finetune_ticks=args.finetune_ticks, pin_cpus=1,
+        restore=lambda p, o, t: restore_or_init(args.ckpt_dir, p, o, t),
+        on_step=save)
+    print(f"feed teardown: {run.teardown}")
+    print(f"final loss {np.mean(run.losses[-20:]):.4f} "
+          f"(first-20 {np.mean(run.losses[:20]):.4f}); "
           f"checkpoints in {args.ckpt_dir}")
 
 
@@ -183,20 +116,20 @@ def run_sim(args):
     """Legacy mode: the tuner tunes a SIMULATED 128-CPU machine; the
     batches fed to the model come from an inline CriteoStream and are
     unaffected by anything the tuner decides."""
-    cfg, params, opt, step_fn = build_model(args.batch)
-    opt_state = opt.init(params)
+    trainer = build_trainer()
+    cfg = trainer.cfg
     stream = CriteoStream(n_sparse=cfg.n_sparse, n_dense=cfg.n_dense,
                           vocab=cfg.vocab_sizes[0])
     tuner = InTune(criteo_pipeline(), MachineSpec(n_cpus=128), seed=0,
                    head="factored", finetune_ticks=150)
-    start, params, opt_state = restore_or_init(
-        args.ckpt_dir, params, opt_state, tuner)
+    start, trainer.params, trainer.opt_state = restore_or_init(
+        args.ckpt_dir, trainer.params, trainer.opt_state, tuner)
     t0 = time.time()
     losses = []
     for i in range(start, args.steps):
         batch = stream.feature_udf(stream.raw_block(args.batch))
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        params, opt_state, metrics = step_fn(params, opt_state, i, batch)
+        metrics = trainer.step(i, {k: jnp.asarray(v)
+                                   for k, v in batch.items()})
         # simulated-pipeline tuning in lockstep with training steps; the
         # closed-loop form is `--backend proc` (FeedBackend + Session.step)
         tuner.tick()
@@ -208,7 +141,8 @@ def run_sim(args):
                   f"{tuner.history[-1]['throughput']:.1f} b/s")
         if (args.ckpt_every and (i + 1) % args.ckpt_every == 0) \
             or i == args.steps - 1:
-            save_step(args.ckpt_dir, i, params, opt_state, tuner)
+            save_step(args.ckpt_dir, i, trainer.params, trainer.opt_state,
+                      tuner)
     print(f"final loss {np.mean(losses[-20:]):.4f} "
           f"(first-20 {np.mean(losses[:20]):.4f}); "
           f"checkpoints in {args.ckpt_dir}")
@@ -232,6 +166,7 @@ def main(argv=None):
                     help="checkpoint cadence in steps; 0 = final step only")
     ap.add_argument("--ckpt-dir", default="experiments/ckpt_dlrm")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.backend == "proc":
         run_proc(args)
     else:

@@ -17,34 +17,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 Axis = Union[str, Sequence[str], None]
 
 
-_SHARD_MAP = None        # (fn, extra-kwargs) resolved once on first use
-_SHARD_MAP_KW: dict = {}
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs):
-    """`jax.shard_map` across JAX versions, replication check disabled.
-
-    The function moved from `jax.experimental.shard_map` to the top level,
-    and the check kwarg was renamed `check_rep` -> `check_vma`; dispatch on
-    the live signature (resolved once) so model code runs on any JAX.
-    """
-    global _SHARD_MAP, _SHARD_MAP_KW
-    if _SHARD_MAP is None:
-        import inspect
-
-        try:
-            from jax import shard_map as _sm
-        except ImportError:  # pragma: no cover
-            from jax.experimental.shard_map import shard_map as _sm
-        params = inspect.signature(_sm).parameters
-        if "check_vma" in params:
-            _SHARD_MAP_KW = {"check_vma": False}
-        elif "check_rep" in params:
-            _SHARD_MAP_KW = {"check_rep": False}
-        _SHARD_MAP = _sm
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_SHARD_MAP_KW)
-
 # Default physical mapping. "fsdp" is the weight-sharding (ZeRO-3) axis;
 # "batch"/"edges"/"tokens" are activation data axes. "pod" composes with
 # "data" so the multi-pod mesh gets hierarchical DP for free.

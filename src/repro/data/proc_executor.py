@@ -45,9 +45,11 @@ and emits a calibrated StageGraph the simulator consumes.
 """
 from __future__ import annotations
 
+import contextlib
 import multiprocessing as mp
 import os
 import queue
+import sys
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence
@@ -621,6 +623,7 @@ class _ProcStagePool:
         self.procs: List = []
         self._soft_flags: List = []
         self._retired: List = []
+        self._retired_flags: List = []
         self.resize(workers)
 
     # ---------------------------------------------------------- control ---
@@ -635,15 +638,23 @@ class _ProcStagePool:
                       self.nworkers_val, self.counter, self.out_gate,
                       self.dropped_ct),
                 daemon=True)
-            p.start()
+            with _main_hidden_from(self._ctx, self.fn):
+                p.start()
             if self._on_spawn is not None:
                 self._on_spawn(p.pid)
             self.procs.append(p)
             self._soft_flags.append(soft)
         while len(self.procs) > n:
-            self._retired = [p for p in self._retired if p.is_alive()]
-            self._soft_flags.pop().set()            # soft stop: delivers
+            live = [k for k, p in enumerate(self._retired) if p.is_alive()]
+            self._retired = [self._retired[k] for k in live]
+            self._retired_flags = [self._retired_flags[k] for k in live]
+            flag = self._soft_flags.pop()
+            flag.set()                              # soft stop: delivers
             self._retired.append(self.procs.pop())
+            # the flag lives as long as its worker: outside "fork" the
+            # parent unlinks an Event's semaphore when it drops the Event,
+            # and a worker still unpickling its arguments would fail
+            self._retired_flags.append(flag)
         # SpinWork reads this to size the Amdahl coordination penalty:
         # the service curve tracks the live pool size
         self.nworkers_val.value = n
@@ -754,6 +765,59 @@ class _RssSampler(threading.Thread):
 # the pipeline
 # ---------------------------------------------------------------------------
 
+def jax_backend_initialized() -> bool:
+    """Has this process brought up a JAX backend (CPU or device)? Reads
+    JAX's state without importing it: a process that never imported jax
+    has no backend."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
+
+
+def default_context():
+    """The start method for worker processes: "fork" where available
+    (closures in `fns` then work), unless this process has brought up a
+    JAX backend — a forked child would inherit the runtime's threads and
+    the device's file handles, and forking a process that holds the TPU
+    can hang. Workers then start from a clean "forkserver" whose only
+    preload is the data plane (no jax)."""
+    methods = mp.get_all_start_methods()
+    if "fork" in methods and not jax_backend_initialized():
+        return mp.get_context("fork")
+    if "forkserver" in methods:
+        ctx = mp.get_context("forkserver")
+        ctx.set_forkserver_preload(["repro.data.featurize"])
+        return ctx
+    return mp.get_context("spawn")
+
+
+@contextlib.contextmanager
+def _main_hidden_from(ctx, fn):
+    """Start a worker without re-importing the parent's `__main__`.
+
+    Outside "fork", Python imports the parent's main module again in
+    every child, so that objects pickled from `__main__` resolve. A
+    worker needs it only when its stage fn lives there; for a trainer
+    script the import is jax and the model code — seconds per worker on
+    every resize, and a worker still importing at teardown misses its
+    join deadline. Hide the main module's identity while the child
+    starts; the parent sees no change once `start()` returns."""
+    main = sys.modules.get("__main__")
+    if (ctx.get_start_method() == "fork" or main is None
+            or getattr(fn, "__module__", None) == "__main__"):
+        yield
+        return
+    saved = {k: main.__dict__[k] for k in ("__spec__", "__file__")
+             if k in main.__dict__}
+    main.__spec__ = None
+    main.__dict__.pop("__file__", None)
+    try:
+        yield
+    finally:
+        main.__dict__.update(saved)
+
+
 class ProcessPipeline:
     """Runs a StageGraph with one OS-process pool per stage;
     `get_batch()` feeds the trainer. ThreadedPipeline's exact contract
@@ -762,9 +826,9 @@ class ProcessPipeline:
     real serialized sections.
 
     `fns` default to `spin_stage_fns(spec)`. Custom fns must be
-    picklable under the chosen start method ("fork" where available, so
-    closures work on Linux; pass `ctx=multiprocessing.get_context(...)`
-    to override).
+    picklable under the chosen start method (`default_context`: "fork"
+    until this process brings up a JAX backend, "forkserver" after; pass
+    `ctx=multiprocessing.get_context(...)` to override).
     """
 
     def __init__(self, spec: StageGraph, *,
@@ -786,11 +850,8 @@ class ProcessPipeline:
         # the feed pipeline to 1 core so JAX keeps the others)
         self.pin_cpus = pin_cpus
         self.prefetch_mb = 2 * self.item_mb
-        if ctx is None:
-            method = "fork" if "fork" in mp.get_all_start_methods() \
-                else "spawn"
-            ctx = mp.get_context(method)
-        self._ctx = ctx
+        self._ctx = ctx if ctx is not None else default_context()
+        ctx = self._ctx
         # calibrate the spin-work clock BEFORE forking, so every worker
         # inherits one shared iterations/CPU-second figure (once per
         # interpreter; spawned workers recalibrate on bind)

@@ -56,7 +56,6 @@ def tp_embedding_lookup(table, ids, mesh):
         return jnp.take(table, ids, axis=0)
     v_loc = v // tp
 
-    from repro.common.shardlib import compat_shard_map as _shard_map
     P = jax.sharding.PartitionSpec
 
     dp_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -77,8 +76,8 @@ def tp_embedding_lookup(table, ids, mesh):
         e = e * ok[..., None].astype(e.dtype)
         return jax.lax.psum(e, "model")
 
-    return _shard_map(f, mesh=mesh, in_specs=(P("model", None), ids_spec),
-                      out_specs=out_spec)(table, ids)
+    return jax.shard_map(f, mesh=mesh, in_specs=(P("model", None), ids_spec),
+                         out_specs=out_spec, check_vma=False)(table, ids)
 
 
 def embedding_bag(table, ids, *, combiner: str = "sum", weights=None):
@@ -170,7 +169,6 @@ def tp_multifeature_bag(tables, ids, mesh, *, combiner: str = "sum"):
         return multifeature_bag(tables, ids, combiner=combiner)
     v_loc = v // n_shards
 
-    from repro.common.shardlib import compat_shard_map as _shard_map
     P = jax.sharding.PartitionSpec
 
     lead = dp_axes if len(dp_axes) > 1 else (dp_axes[0] if dp_axes else None)
@@ -230,12 +228,13 @@ def tp_multifeature_bag(tables, ids, mesh, *, combiner: str = "sum"):
         return jax.vmap(per_feature, in_axes=(1, 1, 1), out_axes=0)(
             lid, ok, g)                        # (F, v_loc, D)
 
-    fwd_sm = _shard_map(fwd_local, mesh=mesh,
-                        in_specs=(P(None, row_axes, None), ids_spec),
-                        out_specs=ids_spec)
-    bwd_sm = _shard_map(bwd_local, mesh=mesh,
-                        in_specs=(ids_spec, ids_spec),
-                        out_specs=P(None, row_axes, None))
+    fwd_sm = jax.shard_map(fwd_local, mesh=mesh,
+                           in_specs=(P(None, row_axes, None), ids_spec),
+                           out_specs=ids_spec, check_vma=False)
+    bwd_sm = jax.shard_map(bwd_local, mesh=mesh,
+                           in_specs=(ids_spec, ids_spec),
+                           out_specs=P(None, row_axes, None),
+                           check_vma=False)
 
     @jax.custom_vjp
     def lookup(tbl, idl):

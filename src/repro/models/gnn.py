@@ -97,7 +97,6 @@ def full_graph_partitioned_loss(params, cfg: GNNConfig, batch, mesh):
     batch: x (N_pad, d) replicated; edge_src/edge_dst (n_shards, e_loc)
     int32 bucketed by dst; labels (N_pad,) sharded (-1 = masked/pad).
     """
-    from repro.common.shardlib import compat_shard_map as _shard_map
     P = jax.sharding.PartitionSpec
     axes = tuple(a for a in ("pod", "data", "model") if a in mesh.axis_names)
     n_shards = 1
@@ -139,8 +138,8 @@ def full_graph_partitioned_loss(params, cfg: GNNConfig, batch, mesh):
         den = jax.lax.psum(jnp.sum(mask), axes)
         return num / jnp.maximum(den, 1.0)
 
-    loss = _shard_map(
-        fn, mesh=mesh,
+    loss = jax.shard_map(
+        fn, mesh=mesh, check_vma=False,
         in_specs=(jax.tree_util.tree_map(lambda _: P(), params),
                   P(None, None), P(row_axes, None), P(row_axes, None),
                   P(row_axes)),

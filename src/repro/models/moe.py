@@ -21,7 +21,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.common.shardlib import compat_shard_map as _shard_map
 from repro.models.layers import activation
 
 P = jax.sharding.PartitionSpec
@@ -187,7 +186,7 @@ def moe_ffn(x, p, cfg, mesh: Optional[jax.sharding.Mesh], e_pad: int):
                           tp_axis=tp_axis if tp > 1 else None,
                           dp_axes=dp_axes)
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh, in_specs=(x_spec, w_specs),
-        out_specs=(x_spec, P()))(x2, p)
+        out_specs=(x_spec, P()), check_vma=False)(x2, p)
     return y.reshape(orig_shape), aux

@@ -333,7 +333,6 @@ def tp_sampled_scores(items, h, cand, mesh):
     embeddings (B, M, C, D). Autodiff scatters d_items into the local row
     shard (§Perf hillclimb 2).
     """
-    from repro.common.shardlib import compat_shard_map as _shard_map
     P = jax.sharding.PartitionSpec
     names = mesh.axis_names
     tp = mesh.shape.get("model", 1)
@@ -359,11 +358,11 @@ def tp_sampled_scores(items, h, cand, mesh):
         part = jnp.einsum("bmd,bmnd->bmn", hl, emb)
         return jax.lax.psum(part, "model")
 
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(P("model", None), P(lead, None, None),
                   P(lead, None, None)),
-        out_specs=P(lead, None, None))(items, h, cand)
+        out_specs=P(lead, None, None), check_vma=False)(items, h, cand)
 
 
 def bert4rec_sampled_logits(params, cfg: RecsysConfig, batch, ctx=None):

@@ -29,19 +29,12 @@ def _quantize_int8(x):
     return q, scale
 
 
-def _axis_size(a):
-    # jax.lax.axis_size is newer JAX; psum(1, axis) is the portable spelling
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(1, a)
-
-
 def psum_tree(tree, axes, *, compress: str = "none", mean: bool = True):
     """All-reduce a grad pytree over `axes` (inside shard_map)."""
     axes = tuple(axes)
     n = 1
     for a in axes:
-        n *= _axis_size(a)
+        n *= jax.lax.axis_size(a)
 
     def reduce_leaf(g):
         if compress == "bf16" and g.dtype == jnp.float32:
