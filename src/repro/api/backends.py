@@ -38,6 +38,7 @@ from repro.api.backend import BackendBase
 from repro.api.events import ChurnEvent
 from repro.api.telemetry import Telemetry
 from repro.api.validation import validate_allocation, validate_fleet_allocation
+from repro.common import tracing
 from repro.data.executor import ThreadedPipeline
 from repro.data.fleet import (ClusterSpec, FleetBackend, FleetEvent,
                               FleetSim, TrainerSpec)
@@ -468,7 +469,8 @@ class FeedBackend(BackendBase):
         if over and not self._over_budget:
             self._oom_count += 1
         self._over_budget = over
-        stats = self.pipe.stats()
+        with tracing.span("pipe_stats"):
+            stats = self.pipe.stats()
         # stats-minus-throughput in extras: the "stage_latency" key is
         # what flips learning observers (InTune._live_obs) onto their
         # measured branch, same as the other live backends

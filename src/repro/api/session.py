@@ -35,6 +35,7 @@ import numpy as np
 from repro.api.backend import Backend
 from repro.api.events import DeadWindow, Event
 from repro.api.telemetry import RunResult, Telemetry
+from repro.common import tracing
 
 
 def _proposal_changed(alloc: Any, prev: Any) -> bool:
@@ -155,14 +156,18 @@ class Session:
         which analytic/self-driving backends treat as a plain tick.
         """
         if tel is None:
-            measure = getattr(self.backend, "measure", None)
-            tel = measure() if callable(measure) \
-                else self.backend.apply(None)
+            with tracing.span("tick_measure"):
+                measure = getattr(self.backend, "measure", None)
+                tel = measure() if callable(measure) \
+                    else self.backend.apply(None)
         if self.optimizer is not None:
-            self.optimizer.observe(tel)
-            alloc = self.optimizer.propose(self.spec, self.backend.machine,
-                                           self.backend.stats())
-            self.backend.apply(alloc)
+            with tracing.span("tick_observe"):
+                self.optimizer.observe(tel)
+            with tracing.span("tick_propose"):
+                alloc = self.optimizer.propose(
+                    self.spec, self.backend.machine, self.backend.stats())
+            with tracing.span("tick_apply"):
+                self.backend.apply(alloc)
         return tel
 
     # --------------------------------------------------------- lifecycle --

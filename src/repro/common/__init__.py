@@ -1,1 +1,2 @@
-from repro.common import pytree, shardlib  # noqa: F401
+"""Shared helpers. The package imports none of its modules, so that the
+pipeline's processes can import `tracing` without bringing in JAX."""

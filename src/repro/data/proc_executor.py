@@ -54,6 +54,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
+from repro.common import tracing
 from repro.data.executor import _RateMeter, ThreadedPipeline
 from repro.data.pipeline import StageGraph
 from repro.data.simulator import MachineSpec
@@ -630,31 +631,37 @@ class _ProcStagePool:
     def resize(self, n: int):
         n = max(1, int(n))
         while len(self.procs) < n:
-            soft = self._ctx.Event()
-            p = self._ctx.Process(
-                target=_worker_main,
-                args=(self.fn, self.in_qs, self.out_qs, soft, self._hard,
-                      self.stop_sent, self.gather_lock, self.serial_lock,
-                      self.nworkers_val, self.counter, self.out_gate,
-                      self.dropped_ct),
-                daemon=True)
-            with _main_hidden_from(self._ctx, self.fn):
-                p.start()
-            if self._on_spawn is not None:
-                self._on_spawn(p.pid)
-            self.procs.append(p)
-            self._soft_flags.append(soft)
+            with tracing.span("worker_start"):
+                soft = self._ctx.Event()
+                p = self._ctx.Process(
+                    target=_worker_main,
+                    args=(self.fn, self.in_qs, self.out_qs, soft, self._hard,
+                          self.stop_sent, self.gather_lock, self.serial_lock,
+                          self.nworkers_val, self.counter, self.out_gate,
+                          self.dropped_ct),
+                    daemon=True)
+                with _main_hidden_from(self._ctx, self.fn):
+                    p.start()
+                if self._on_spawn is not None:
+                    self._on_spawn(p.pid)
+                self.procs.append(p)
+                self._soft_flags.append(soft)
+                tracing.count("workers_started")
         while len(self.procs) > n:
-            live = [k for k, p in enumerate(self._retired) if p.is_alive()]
-            self._retired = [self._retired[k] for k in live]
-            self._retired_flags = [self._retired_flags[k] for k in live]
-            flag = self._soft_flags.pop()
-            flag.set()                              # soft stop: delivers
-            self._retired.append(self.procs.pop())
-            # the flag lives as long as its worker: outside "fork" the
-            # parent unlinks an Event's semaphore when it drops the Event,
-            # and a worker still unpickling its arguments would fail
-            self._retired_flags.append(flag)
+            with tracing.span("worker_retire"):
+                live = [k for k, p in enumerate(self._retired)
+                        if p.is_alive()]
+                self._retired = [self._retired[k] for k in live]
+                self._retired_flags = [self._retired_flags[k] for k in live]
+                flag = self._soft_flags.pop()
+                flag.set()                          # soft stop: delivers
+                self._retired.append(self.procs.pop())
+                # the flag lives as long as its worker: outside "fork" the
+                # parent unlinks an Event's semaphore when it drops the
+                # Event, and a worker still unpickling its arguments would
+                # fail
+                self._retired_flags.append(flag)
+                tracing.count("workers_retired")
         # SpinWork reads this to size the Amdahl coordination penalty:
         # the service curve tracks the live pool size
         self.nworkers_val.value = n
@@ -729,19 +736,20 @@ class _RssSampler(threading.Thread):
         self._baselines = baselines
         self.interval = interval
         self.rss_mb = 0.0
-        self.peak_mb = 0.0
         self._halt = threading.Event()
 
     def sample(self) -> float:
         total, got = 0.0, False
-        for pid in self._pids_fn():
-            mb = read_rss_mb(pid)
-            if mb is not None:
-                total += max(0.0, mb - self._baselines.get(pid, 0.0))
-                got = True
+        with tracing.span("rss_scan"):
+            pids = self._pids_fn()
+            for pid in pids:
+                mb = read_rss_mb(pid)
+                if mb is not None:
+                    total += max(0.0, mb - self._baselines.get(pid, 0.0))
+                    got = True
+            tracing.count("rss_pids_read", len(pids))
         if got:
             self.rss_mb = total
-            self.peak_mb = max(self.peak_mb, total)
         return self.rss_mb
 
     def run(self):
@@ -909,7 +917,9 @@ class ProcessPipeline:
         """Per-worker spawn hook: record the memory baseline (the
         sampler charges growth since spawn, not the inherited image —
         see _RssSampler) and pin the worker to the capped core set."""
-        self._rss_baseline[pid] = read_rss_mb(pid) or 0.0
+        with tracing.span("rss_scan"):
+            self._rss_baseline[pid] = read_rss_mb(pid) or 0.0
+            tracing.count("rss_pids_read")
         self._pin_worker(pid)
 
     def _pin_worker(self, pid: int):

@@ -47,19 +47,27 @@ def dot_interaction(feats):
 
 
 def forward(params, cfg: DLRMConfig, batch, *, interact_fn=None, ctx=None):
-    """batch: sparse_ids (B, 26, hot), dense (B, 13) -> logits (B,)."""
-    dense_out = apply_mlp(params["bottom"],
-                          batch["dense"].astype(params["tables"].dtype),
-                          final_act=True)
-    if cfg.tp_lookup and ctx is not None:
-        emb = tp_multifeature_bag(params["tables"], batch["sparse_ids"],
-                                  ctx.mesh)
-    else:
-        emb = multifeature_bag(params["tables"], batch["sparse_ids"])
-    feats = jnp.concatenate([dense_out[:, None, :], emb], axis=1)  # (B,27,D)
-    interact = (interact_fn or dot_interaction)(feats)
-    top_in = jnp.concatenate([interact, dense_out], axis=-1)
-    return apply_mlp(params["top"], top_in)[:, 0]
+    """batch: sparse_ids (B, 26, hot), dense (B, 13) -> logits (B,).
+
+    Each part runs under a `jax.named_scope` (`bottom_mlp`,
+    `embedding_lookup`, `interaction`, `top_mlp`) that the compiled
+    ops, their gradients included, carry in their metadata."""
+    with jax.named_scope("bottom_mlp"):
+        dense_out = apply_mlp(params["bottom"],
+                              batch["dense"].astype(params["tables"].dtype),
+                              final_act=True)
+    with jax.named_scope("embedding_lookup"):
+        if cfg.tp_lookup and ctx is not None:
+            emb = tp_multifeature_bag(params["tables"], batch["sparse_ids"],
+                                      ctx.mesh)
+        else:
+            emb = multifeature_bag(params["tables"], batch["sparse_ids"])
+    with jax.named_scope("interaction"):
+        feats = jnp.concatenate([dense_out[:, None, :], emb], axis=1)  # (B,27,D)
+        interact = (interact_fn or dot_interaction)(feats)
+    with jax.named_scope("top_mlp"):
+        top_in = jnp.concatenate([interact, dense_out], axis=-1)
+        return apply_mlp(params["top"], top_in)[:, 0]
 
 
 def loss_fn(params, cfg: DLRMConfig, batch, *, interact_fn=None, ctx=None):

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.common import shardlib
+from repro.common import shardlib, tracing
 from repro.configs.base import DLRMConfig
 from repro.data.featurize import RecordSpec, featurize_block, raw_block
 from repro.data.simulator import Allocation, MachineSpec
@@ -154,6 +154,7 @@ class FeedRun:
     examples_per_s: float       # over the steps after the first
     workers: List[int]
     teardown: Dict[str, Any]
+    spans: Dict[str, Dict[str, float]]   # tracing.since(the call's start)
 
 
 def train_on_feed(trainer: DLRMTrainer, record: RecordSpec, *, steps: int,
@@ -180,6 +181,7 @@ def train_on_feed(trainer: DLRMTrainer, record: RecordSpec, *, steps: int,
     from repro.data.pipeline import train_feed_pipeline
     from repro.data.proc_executor import ProcessPipeline
 
+    before = tracing.snapshot()
     # warm the step and measure its uncontended device time: the
     # pipeline's CPU budget (train_feed_pipeline cpu_share) is set
     # relative to THIS, so ingestion can keep up at a sane allocation
@@ -223,25 +225,41 @@ def train_on_feed(trainer: DLRMTrainer, record: RecordSpec, *, steps: int,
     losses: List[float] = []
     t0 = time.monotonic()
     t_first = None          # after the first loss: pipeline cold start over
+    # each iteration is tiled by the spans feed_wait, step_dispatch,
+    # host_loop (loss_read, tune_sync) and, every `tune_every` steps,
+    # tuner_tick; the caller's hooks run between them
     try:
         for i in range(start, steps):
-            batch = next(feed)
-            if on_batch is not None:
-                on_batch(i, trainer.params, batch)
-            metrics = trainer.step(i, batch)
-            losses.append(float(metrics["loss"]))
-            if t_first is None:
-                t_first = time.monotonic()
-            if i % tune_every == 0:
-                jax.block_until_ready(trainer.params)  # close the window
-                session.step()
-            if log_every and i % log_every == 0:
-                rate = len(losses) * record.batch / (time.monotonic() - t0)
-                print(f"step {i:4d} loss {losses[-1]:.4f} "
-                      f"({rate:,.0f} examples/s wall-clock) "
-                      f"workers {pipe.worker_counts()}")
-            if on_step is not None:
-                on_step(i, trainer.params, trainer.opt_state, tuner)
+            with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                with tracing.span("feed_wait"):
+                    batch = next(feed)
+                if on_batch is not None:
+                    on_batch(i, trainer.params, batch)
+                with tracing.span("step_dispatch"):
+                    metrics = trainer.step(i, batch)
+                tick = i % tune_every == 0
+                with tracing.span("host_loop"):
+                    with tracing.span("loss_read"):
+                        losses.append(float(metrics["loss"]))
+                    if t_first is None:
+                        t_first = time.monotonic()
+                    if tick:
+                        with tracing.span("tune_sync"):
+                            # close the window
+                            jax.block_until_ready(trainer.params)
+                if tick:
+                    with tracing.span("tuner_tick"):
+                        session.step()
+                if log_every and i % log_every == 0:
+                    rate = len(losses) * record.batch / (time.monotonic() - t0)
+                    ticks = tracing.since(before).get("tuner_tick")
+                    tick_ms = ticks["s"] / ticks["n"] * 1e3 if ticks else 0.0
+                    print(f"step {i:4d} loss {losses[-1]:.4f} "
+                          f"({rate:,.0f} examples/s wall-clock) "
+                          f"workers {pipe.worker_counts()} "
+                          f"tick {tick_ms:.1f} ms")
+                if on_step is not None:
+                    on_step(i, trainer.params, trainer.opt_state, tuner)
         t_end = time.monotonic()
         workers = list(pipe.worker_counts())
     finally:
@@ -249,4 +267,5 @@ def train_on_feed(trainer: DLRMTrainer, record: RecordSpec, *, steps: int,
     warm = (len(losses) - 1) * record.batch / (t_end - t_first) \
         if len(losses) > 1 else 0.0
     return FeedRun(losses=losses, step_time_s=step_time,
-                   examples_per_s=warm, workers=workers, teardown=acct)
+                   examples_per_s=warm, workers=workers, teardown=acct,
+                   spans=tracing.since(before))

@@ -211,7 +211,9 @@ def rowwise_adagrad(lr_fn, eps: float = 1e-10,
         a_leaves = treedef.flatten_up_to(state["acc"])
         new_p, new_a = [], []
         for p, g, a in zip(p_leaves, g_leaves, a_leaves):
-            np_, na = _chunked(leaf, p, g, a)
+            scope = "table_update" if a.shape != p.shape else "dense_update"
+            with jax.named_scope(scope):
+                np_, na = _chunked(leaf, p, g, a)
             new_p.append(np_)
             new_a.append(na)
         unf = lambda leaves: jax.tree_util.tree_unflatten(treedef, leaves)

@@ -54,8 +54,9 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
                 body, (zero_g, jnp.zeros((), jnp.float32)), mb)
             metrics = jax.tree_util.tree_map(jnp.mean, metrics_stack)
 
-        new_params, new_opt, stats = optimizer.update(
-            grads, opt_state, params, step)
+        with jax.named_scope("optimizer_update"):
+            new_params, new_opt, stats = optimizer.update(
+                grads, opt_state, params, step)
         out = dict(metrics)
         out.update(stats)
         out["loss"] = loss

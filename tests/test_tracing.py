@@ -1,0 +1,197 @@
+"""The program's spans, counters and named scopes (`repro.common.tracing`,
+`jax.named_scope`): the span table by hand and under a second thread,
+the served loop's spans and counters on a tiny CPU run, the same run's
+profiler trace, and the scopes in the compiled train step."""
+import glob
+import multiprocessing.process
+import re
+import subprocess
+import sys
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.common import tracing
+from repro.configs.base import DLRMConfig
+from repro.data.simulator import MachineSpec
+from repro.models import dlrm
+from repro.train.feed_loop import DLRMTrainer, criteo_record, train_on_feed
+from repro.train.optim import make_optimizer
+from repro.train.train_step import make_train_step
+
+TINY = DLRMConfig(name="dlrm-tiny", n_sparse=4, n_dense=13, embed_dim=16,
+                  vocab_sizes=(512,) * 4, bottom_mlp=(32, 16), top_mlp=(32, 1),
+                  multi_hot=1)
+LOOP = ("feed_wait", "step_dispatch", "host_loop", "loss_read")
+
+
+# ---------------------------------------------------------- the table --
+def test_span_table_counts_and_times_nested_spans():
+    before = tracing.snapshot()
+    with tracing.span("t_outer"):
+        for _ in range(3):
+            with tracing.span("t_inner"):
+                time.sleep(0.01)
+    got = tracing.since(before)
+    assert got["t_outer"]["n"] == 1 and got["t_inner"]["n"] == 3
+    assert got["t_inner"]["s"] >= 0.03
+    assert got["t_inner"]["s"] <= got["t_outer"]["s"]
+    assert set(got) == {"t_outer", "t_inner"}
+
+
+def test_span_table_is_exact_under_a_second_thread():
+    before = tracing.snapshot()
+
+    def work():
+        for _ in range(2000):
+            with tracing.span("t_thread"):
+                pass
+            tracing.count("t_counter", 2)
+
+    threads = [threading.Thread(target=work) for _ in range(2)]
+    for t in threads:
+        t.start()
+    work()
+    for t in threads:
+        t.join()
+    got = tracing.since(before)
+    assert got["t_thread"]["n"] == 6000
+    assert got["t_counter"] == {"n": 12000, "s": 0.0}
+
+
+def test_a_span_raising_is_still_counted():
+    before = tracing.snapshot()
+    with pytest.raises(KeyError):
+        with tracing.span("t_raises"):
+            raise KeyError("x")
+    assert tracing.since(before)["t_raises"]["n"] == 1
+
+
+def test_tracing_brings_in_no_jax():
+    """The pipeline's processes import the tracing module through
+    proc_executor; it must not import JAX there (the forkserver preloads
+    the data plane without it) and still keeps the table."""
+    code = ("import sys\n"
+            "import repro.data.featurize\n"
+            "from repro.common import tracing\n"
+            "with tracing.span('x'):\n"
+            "    tracing.count('y', 3)\n"
+            "print('jax' in sys.modules, tracing.snapshot()['y']['n'])\n")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         capture_output=True, text=True, timeout=120)
+    assert out.stdout.split() == ["False", "3"]
+
+
+# --------------------------------------------------------- the served loop --
+@pytest.fixture(scope="module")
+def traced_run(tmp_path_factory):
+    """The tiny served loop under a profiler trace: its FeedRun, the
+    processes it started, and the trace."""
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    started = []
+    orig = multiprocessing.process.BaseProcess.start
+
+    def start(self):
+        started.append(self.name)
+        return orig(self)
+
+    trainer = DLRMTrainer(TINY, optimizer="rowwise_adagrad", lr=0.02)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    multiprocessing.process.BaseProcess.start = start
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        run = train_on_feed(trainer, criteo_record(TINY, batch=64), steps=5,
+                            tune_every=2, machine=MachineSpec(n_cpus=2, mem_mb=4096),
+                            log_every=0)
+    finally:
+        jax.profiler.stop_trace()
+        multiprocessing.process.BaseProcess.start = orig
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    return run, started, jax.profiler.ProfileData.from_file(path)
+
+
+def test_loop_spans_once_per_step_and_tick_every_tune_every(traced_run):
+    run, _, _ = traced_run
+    steps = len(run.losses)
+    assert steps == 5
+    for name in LOOP:
+        assert run.spans[name]["n"] == steps, name
+    # ticks after steps 0, 2, 4; each blocks first, then measures,
+    # observes, proposes and applies once
+    for name in ("tuner_tick", "tune_sync", "tick_measure", "tick_observe",
+                 "tick_propose", "tick_apply", "pipe_stats"):
+        assert run.spans[name]["n"] == 3, name
+    tick = run.spans["tuner_tick"]["s"]
+    parts = sum(run.spans[n]["s"] for n in ("tick_measure", "tick_observe",
+                                            "tick_propose", "tick_apply"))
+    assert parts <= tick
+
+
+def test_loop_counts_the_workers_the_pipeline_started(traced_run):
+    run, started, _ = traced_run
+    assert run.spans["workers_started"]["n"] == len(started) > 0
+    assert run.spans["worker_start"]["n"] == len(started)
+    assert run.spans.get("workers_retired", {"n": 0})["n"] == \
+        run.spans.get("worker_retire", {"n": 0})["n"]
+    assert run.spans["rss_pids_read"]["n"] >= run.spans["rss_scan"]["n"] > 0
+
+
+def test_profiler_trace_holds_the_spans_nested_on_one_host_line(traced_run):
+    _, _, data = traced_run
+    lines = [list(line.events) for plane in data.planes
+             if plane.name.startswith("/host:") for line in plane.lines]
+    loop = [evs for evs in lines if any(e.name == "tuner_tick" for e in evs)]
+    assert len(loop) == 1
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns) for e in loop[0]]
+
+    def named(name):
+        return [(s, e) for n, s, e in events if n == name]
+
+    def inside(inner, outer):
+        return all(any(os <= s and e <= oe for os, oe in named(outer))
+                   for s, e in named(inner))
+
+    assert len(named("train")) == 5
+    for name in LOOP + ("tuner_tick",):
+        assert inside(name, "train"), name
+    for name in ("loss_read", "tune_sync"):
+        assert inside(name, "host_loop"), name
+    for name in ("tick_measure", "tick_observe", "tick_propose", "tick_apply"):
+        assert len(named(name)) == 3 and inside(name, "tuner_tick"), name
+    assert inside("pipe_stats", "tick_measure")
+    # the loop's four spans are siblings: none overlaps another
+    tiles = sorted(s for name in LOOP[:3] + ("tuner_tick",) for s in named(name))
+    assert all(a[1] <= b[0] for a, b in zip(tiles, tiles[1:]))
+    # the workers started before the first step carry their counter
+    starts = [e for e in loop[0] if e.name == "workers_started"]
+    assert starts and all(dict(e.stats)["n"] == 1 for e in starts)
+
+
+# --------------------------------------------------------- the step's scopes --
+def test_compiled_step_carries_the_scopes():
+    """The gather of the looked-up rows and the scatter of their gradient
+    carry `embedding_lookup`, the row-wise update `table_update`, each
+    MLP its own scope."""
+    params, _ = dlrm.init_params(jax.random.PRNGKey(0), TINY)
+    opt = make_optimizer("rowwise_adagrad", lr=0.02, rowwise_min_elems=1024)
+    step = make_train_step(lambda p, b: dlrm.loss_fn(p, TINY, b), opt)
+    batch = {"sparse_ids": jnp.zeros((64, 4, 1), jnp.int32),
+             "dense": jnp.zeros((64, 13)), "label": jnp.zeros((64,))}
+    text = jax.jit(step).lower(params, opt.init(params), 0, batch).compile().as_text()
+    ops = [(m.group(1), m.group(2)) for m in re.finditer(
+        r"= \S+ (\w[\w-]*)\(.*op_name=\"([^\"]*)\"", text)]
+
+    def scoped(kind, scope):
+        return [n for k, n in ops if k == kind and re.search(rf"\b{scope}\b", n)]
+
+    assert scoped("gather", "embedding_lookup")
+    assert any("transpose" in n for n in scoped("scatter", "embedding_lookup"))
+    assert scoped("subtract", "table_update") and scoped("rsqrt", "table_update")
+    assert all("optimizer_update" in n for n in
+               [n for _, n in ops if "table_update" in n or "dense_update" in n])
+    for scope in ("bottom_mlp", "interaction", "top_mlp", "dense_update"):
+        assert any(re.search(rf"\b{scope}\b", n) for _, n in ops), scope
