@@ -12,8 +12,9 @@ widths on the chip, checked against a float32 reference):
     `tp_multifeature_bag`);
   - `train_on_feed` runs the closed loop: the pipeline's worker
     processes featurize raw click records, batches cross onto the device
-    through the metered feed, and InTune retunes the pipeline between
-    train steps against the measured feed telemetry.
+    through the metered feed, and InTune retunes the pipeline against
+    the measured feed telemetry while the device runs the step just
+    dispatched.
 
 The pipeline is created only after the model is on the device, so its
 workers start from a clean forkserver (`proc_executor.default_context`),
@@ -166,13 +167,16 @@ def train_on_feed(trainer: DLRMTrainer, record: RecordSpec, *, steps: int,
                   on_step: Optional[Callable] = None,
                   log_every: int = 25) -> FeedRun:
     """Train `trainer` for `steps` steps on batches a tuned
-    ProcessPipeline featurizes from raw `record`s.
+    ProcessPipeline featurizes from raw `record`s. After every
+    `tune_every`-th step is dispatched, and before its loss is read, the
+    tuner ticks (`Session.step`), so its host work overlaps the step on
+    the device.
 
     Hooks (all optional):
       restore(params, opt_state, tuner) -> (start, params, opt_state)
           resume from a checkpoint before the first step;
       on_batch(i, params, batch)   before step i, with the device batch;
-      on_step(i, params, opt_state, tuner)   after step i.
+      on_step(i, params, opt_state, tuner)   after step i's loss is read.
     """
     from repro.api import FeedBackend, Session
     from repro.core.controller import InTune
@@ -226,8 +230,8 @@ def train_on_feed(trainer: DLRMTrainer, record: RecordSpec, *, steps: int,
     t0 = time.monotonic()
     t_first = None          # after the first loss: pipeline cold start over
     # each iteration is tiled by the spans feed_wait, step_dispatch,
-    # host_loop (loss_read, tune_sync) and, every `tune_every` steps,
-    # tuner_tick; the caller's hooks run between them
+    # every `tune_every` steps tuner_tick, and host_loop (loss_read); the
+    # caller's hooks run between them
     try:
         for i in range(start, steps):
             with jax.profiler.StepTraceAnnotation("train", step_num=i):
@@ -237,19 +241,21 @@ def train_on_feed(trainer: DLRMTrainer, record: RecordSpec, *, steps: int,
                     on_batch(i, trainer.params, batch)
                 with tracing.span("step_dispatch"):
                     metrics = trainer.step(i, batch)
-                tick = i % tune_every == 0
+                if i % tune_every == 0:
+                    # the tick's host work runs while the device runs
+                    # step i; the window it measures holds the steps
+                    # through i-1, whose losses the loop has read
+                    with tracing.span("tuner_tick"):
+                        session.step()
+                        if metrics["loss"].is_ready():
+                            # step i ended first: the device idled for
+                            # the rest of the tick
+                            tracing.count("ticks_outlasting_step")
                 with tracing.span("host_loop"):
                     with tracing.span("loss_read"):
                         losses.append(float(metrics["loss"]))
                     if t_first is None:
                         t_first = time.monotonic()
-                    if tick:
-                        with tracing.span("tune_sync"):
-                            # close the window
-                            jax.block_until_ready(trainer.params)
-                if tick:
-                    with tracing.span("tuner_tick"):
-                        session.step()
                 if log_every and i % log_every == 0:
                     rate = len(losses) * record.batch / (time.monotonic() - t0)
                     ticks = tracing.since(before).get("tuner_tick")

@@ -3,6 +3,7 @@
 the served loop's spans and counters on a tiny CPU run, the same run's
 profiler trace, and the scopes in the compiled train step."""
 import glob
+import math
 import multiprocessing.process
 import re
 import subprocess
@@ -120,11 +121,12 @@ def test_loop_spans_once_per_step_and_tick_every_tune_every(traced_run):
     assert steps == 5
     for name in LOOP:
         assert run.spans[name]["n"] == steps, name
-    # ticks after steps 0, 2, 4; each blocks first, then measures,
-    # observes, proposes and applies once
-    for name in ("tuner_tick", "tune_sync", "tick_measure", "tick_observe",
+    # ticks after steps 0, 2, 4 are dispatched; each measures, observes,
+    # proposes and applies once, with no wait on the device first
+    for name in ("tuner_tick", "tick_measure", "tick_observe",
                  "tick_propose", "tick_apply", "pipe_stats"):
         assert run.spans[name]["n"] == 3, name
+    assert "tune_sync" not in run.spans
     tick = run.spans["tuner_tick"]["s"]
     parts = sum(run.spans[n]["s"] for n in ("tick_measure", "tick_observe",
                                             "tick_propose", "tick_apply"))
@@ -158,17 +160,89 @@ def test_profiler_trace_holds_the_spans_nested_on_one_host_line(traced_run):
     assert len(named("train")) == 5
     for name in LOOP + ("tuner_tick",):
         assert inside(name, "train"), name
-    for name in ("loss_read", "tune_sync"):
-        assert inside(name, "host_loop"), name
+    assert inside("loss_read", "host_loop")
     for name in ("tick_measure", "tick_observe", "tick_propose", "tick_apply"):
         assert len(named(name)) == 3 and inside(name, "tuner_tick"), name
     assert inside("pipe_stats", "tick_measure")
+
+    # a tick runs between its step's dispatch and that step's loss read
+    def within(name, lo, hi):
+        return [(s, e) for s, e in named(name) if lo <= s and e <= hi]
+
+    ticks = named("tuner_tick")
+    assert len(ticks) == 3
+    for ts, te in ticks:
+        (lo, hi), = [(s, e) for s, e in named("train") if s <= ts and te <= e]
+        (_, dispatched), = within("step_dispatch", lo, hi)
+        (read, _), = within("loss_read", lo, hi)
+        assert dispatched <= ts and te <= read
     # the loop's four spans are siblings: none overlaps another
     tiles = sorted(s for name in LOOP[:3] + ("tuner_tick",) for s in named(name))
     assert all(a[1] <= b[0] for a, b in zip(tiles, tiles[1:]))
     # the workers started before the first step carry their counter
     starts = [e for e in loop[0] if e.name == "workers_started"]
     assert starts and all(dict(e.stats)["n"] == 1 for e in starts)
+
+
+def test_tick_runs_while_its_step_is_in_flight(monkeypatch):
+    """Each tick starts after `trainer.step(i)` returns and ends before
+    step i's loss becomes a float; `on_batch` and `on_step` keep their
+    places, and `on_step` sees step i's own parameters. A tick whose
+    step had already ended counts `ticks_outlasting_step` once: here
+    steps 0 and 4 have ended, and step 2 reports itself still running."""
+    from repro.api import Session
+    events, params_after = [], {}
+
+    class Loss:
+        """Step i's loss, recording when the loop reads it."""
+
+        def __init__(self, i, arr):
+            self.i, self.arr = i, arr
+
+        def is_ready(self):
+            return self.i != 2 and self.arr.is_ready()
+
+        def __float__(self):
+            events.append(("loss_read", self.i))
+            return float(self.arr)
+
+    trainer = DLRMTrainer(TINY, optimizer="rowwise_adagrad", lr=0.02)
+    orig_step, orig_tick = trainer.step, Session.step
+
+    def step(i, batch):
+        m = orig_step(i, batch)
+        if not events:                  # the warm-up's steps
+            return m
+        jax.block_until_ready(m["loss"])
+        params_after[i] = trainer.params
+        events.append(("step", i))
+        return {**m, "loss": Loss(i, m["loss"])}
+
+    def tick(self, *args, **kwargs):
+        events.append(("tick", "start"))
+        out = orig_tick(self, *args, **kwargs)
+        events.append(("tick", "end"))
+        return out
+
+    def on_step(i, params, opt_state, tuner):
+        assert params is params_after[i]
+        events.append(("on_step", i))
+
+    trainer.step = step
+    monkeypatch.setattr(Session, "step", tick)
+    run = train_on_feed(trainer, criteo_record(TINY, batch=64), steps=5,
+                        tune_every=2, machine=MachineSpec(n_cpus=2, mem_mb=4096),
+                        on_batch=lambda i, p, b: events.append(("batch", i)),
+                        on_step=on_step, log_every=0)
+    expected = []
+    for i in range(5):
+        expected += [("batch", i), ("step", i)]
+        if i % 2 == 0:
+            expected += [("tick", "start"), ("tick", "end")]
+        expected += [("loss_read", i), ("on_step", i)]
+    assert events == expected
+    assert len(run.losses) == 5 and all(map(math.isfinite, run.losses))
+    assert run.spans["ticks_outlasting_step"] == {"n": 2, "s": 0.0}
 
 
 # --------------------------------------------------------- the step's scopes --
