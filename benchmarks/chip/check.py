@@ -20,12 +20,13 @@ but the rule stands in `reference.gaps`.
 The fed batches (what the pipeline's featurization produced) cannot be
 regenerated: the pipeline's workers seed their record draws from their
 process ids. They are held to what featurization guarantees: shapes and
-dtypes, ids inside the table, one-hot bags, labels of 0 or 1, dense
-features finite and standardised per batch, and rows that all differ.
+dtypes (the architecture's, `archs/<model>.feed_faults`), ids inside
+their tables, labels of 0 or 1, dense features finite and standardised
+per batch, and rows that all differ.
 """
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import numpy as np
 
@@ -47,22 +48,28 @@ def worst_leaves(program: Dict, ref: Dict) -> Dict[str, str]:
             "change_gap": reference.gaps(program["change"], ref["change"], first)[1]}
 
 
-def feed_faults(batches: List[Dict[str, np.ndarray]], cfg: Dict) -> List[str]:
-    """What the fed batches break of featurization's guarantees."""
-    b, f, d = cfg["batch"], cfg["n_sparse"], cfg["n_dense"]
-    want = {"sparse_ids": ((b, f, cfg["multi_hot"]), np.int32),
-            "dense": ((b, d), np.float32), "label": ((b,), np.float32)}
+def feed_faults(batches: List[Dict[str, np.ndarray]],
+                want: Dict[str, Tuple[Tuple[int, ...], type]], ids: str,
+                rows) -> List[str]:
+    """What the fed batches break of featurization's guarantees: each
+    key of `want` of its (shape, dtype); the ids under key `ids` in [0,
+    rows) (a count, or one per id broadcast over its trailing axes);
+    labels of 0 or 1; `dense` finite and standardised; and no row (all
+    keys but the label) repeated."""
+    b = want["label"][0][0]
     faults: List[str] = []
-    rows = []
+    rows_seen = []
     for n, batch in enumerate(batches):
-        for k, (shape, dtype) in want.items():
-            if k not in batch or batch[k].shape != shape or batch[k].dtype != dtype:
-                faults.append(f"batch {n}: {k} is not {dtype.__name__}{shape}")
-        if faults:
+        bad = [f"batch {n}: {k} is not {dtype.__name__}{shape}"
+               for k, (shape, dtype) in want.items()
+               if k not in batch or batch[k].shape != shape or batch[k].dtype != dtype]
+        faults += bad
+        if bad:
             continue
-        ids, dense, label = batch["sparse_ids"], batch["dense"], batch["label"]
-        if ids.min() < 0 or ids.max() >= cfg["rows_per_table"]:
-            faults.append(f"batch {n}: ids outside [0, {cfg['rows_per_table']})")
+        dense, label = batch["dense"], batch["label"]
+        if batch[ids].min() < 0 or (batch[ids] >= rows).any():
+            where = f"[0, {rows})" if np.ndim(rows) == 0 else "their tables"
+            faults.append(f"batch {n}: ids outside {where}")
         if not np.isin(label, (0.0, 1.0)).all():
             faults.append(f"batch {n}: labels other than 0 and 1")
         if not np.isfinite(dense).all():
@@ -70,10 +77,10 @@ def feed_faults(batches: List[Dict[str, np.ndarray]], cfg: Dict) -> List[str]:
         elif (np.abs(dense.mean(0)).max() > 1e-3
               or np.abs(dense.std(0) - 1.0).max() > 1e-3):
             faults.append(f"batch {n}: dense features not standardised")
-        rows.append(np.concatenate([ids.reshape(b, -1).view(np.uint32),
-                                    dense.view(np.uint32)], axis=1))
-    if rows:
-        allrows = np.ascontiguousarray(np.concatenate(rows))
+        rows_seen.append(np.concatenate([batch[k].reshape(b, -1).view(np.uint32)
+                                         for k in want if k != "label"], axis=1))
+    if rows_seen:
+        allrows = np.ascontiguousarray(np.concatenate(rows_seen))
         distinct = np.unique(allrows.view(np.dtype((np.void, allrows.shape[1] * 4))))
         if distinct.size != allrows.shape[0]:
             faults.append(f"{allrows.shape[0] - distinct.size} fed rows repeat")

@@ -35,11 +35,12 @@ import jax.numpy as jnp
 import numpy as np
 
 import check
+import layout
 import procstat
 import reference
 
 CHECKED = 3          # fed steps the reference replays
-WARM_STEPS = (0, 0, 1, 2)   # DLRMTrainer.warm_up's step indices
+WARM_STEPS = (0, 0, 1, 2)   # the trainer's warm_up: its step indices
 TUNER_MEM_MB = 16384        # the tuner's memory budget for the workers
 
 
@@ -47,39 +48,21 @@ class WindowClosed(Exception):
     """Raised from a hook to end the loop once the window has closed."""
 
 
-def model_config(cfg: Dict):
-    from repro.configs.base import DLRMConfig
-    return DLRMConfig(
-        name=cfg["name"], n_sparse=cfg["n_sparse"], n_dense=cfg["n_dense"],
-        embed_dim=cfg["embed_dim"],
-        vocab_sizes=(cfg["rows_per_table"],) * cfg["n_sparse"],
-        bottom_mlp=tuple(cfg["bottom_mlp"]), top_mlp=tuple(cfg["top_mlp"]),
-        multi_hot=cfg["multi_hot"], param_dtype=cfg["param_dtype"],
-        tp_lookup=cfg["tp_lookup"],
-        sharding_overrides=tuple((k, tuple(v) if isinstance(v, list) else v)
-                                 for k, v in cfg["sharding_overrides"]))
-
-
-def record_spec(cfg: Dict, traffic: Dict, seed: int):
-    from repro.data.featurize import RecordSpec
-    return RecordSpec(batch=cfg["batch"], n_sparse=cfg["n_sparse"],
-                      n_dense=cfg["n_dense"], vocab=cfg["rows_per_table"],
-                      hot=cfg["multi_hot"], seed=int(seed) % (1 << 32),
-                      **traffic["record"])
+def arch(cfg: Dict):
+    """The configuration's architecture (`archs/<model>.py`)."""
+    return layout.arch(cfg["model"])
 
 
 def make_trainer(cfg: Dict, devices: List):
     """The program's trainer for `cfg`, on one device or over the mesh
     the configuration states."""
-    from repro.train.feed_loop import DLRMTrainer
     mesh = None
     if cfg.get("mesh"):
         axes = tuple(cfg["mesh"])
         shape = tuple(cfg["mesh"][a] for a in axes)
         mesh = jax.make_mesh(shape, axes, (jax.sharding.AxisType.Auto,) * len(axes),
                              devices=devices[:int(np.prod(shape))])
-    return DLRMTrainer(model_config(cfg), optimizer=cfg["optimizer"],
-                       lr=cfg["lr"], seed=0, mesh=mesh)
+    return arch(cfg).make_trainer(cfg, mesh)
 
 
 def put_weights(trainer, cfg: Dict, seed: int) -> None:
@@ -91,7 +74,8 @@ def put_weights(trainer, cfg: Dict, seed: int) -> None:
     trainer.params = trainer.opt_state = None
     gc.collect()
     dtype = jnp.dtype(cfg["param_dtype"])
-    trainer.params = jax.jit(lambda w: reference.init_params(w, cfg, dtype),
+    init = arch(cfg).init_params
+    trainer.params = jax.jit(lambda w: init(w, cfg, dtype),
                              out_shardings=p_sh, compiler_options=reference.EXACT)(
         reference.seed_words(seed))
     trainer.opt_state = jax.jit(trainer.opt.init, out_shardings=o_sh)(trainer.params)
@@ -113,7 +97,7 @@ def _grad_from_acc(acc0, acc1, params):
 
 
 def _change(params, words, cfg):
-    init = reference.init_params(words, cfg, jnp.dtype(cfg["param_dtype"]))
+    init = arch(cfg).init_params(words, cfg, jnp.dtype(cfg["param_dtype"]))
     return reference.leaf_norms(jax.tree_util.tree_map(
         lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), params, init))
 
@@ -306,7 +290,7 @@ def drive(trainer, cell: Dict, seed: int, seconds: float,
     cpus = len(os.sched_getaffinity(0)) - int(t["tuner_reserved_cpus"])
     machine = MachineSpec(n_cpus=max(1, cpus), mem_mb=TUNER_MEM_MB)
     try:
-        train_on_feed(trainer, record_spec(cell["config"], t, seed),
+        train_on_feed(trainer, arch(cell["config"]).record_spec(cell["config"], t, seed),
                       steps=1 << 40, tune_every=int(t["tune_every"]),
                       finetune_ticks=int(t["finetune_ticks"]), machine=machine,
                       restore=run.restore,
@@ -333,15 +317,16 @@ def reference_readings(run: FedRun, cell: Dict, *, device=None,
                        quant: str = "float32", fault: str = "") -> Dict:
     from repro.train.feed_loop import warm_batch
     cfg = cell["config"]
-    warm = warm_batch(record_spec(cfg, cell["traffic"], run.seed))
+    model = arch(cfg)
+    warm = warm_batch(model.record_spec(cfg, cell["traffic"], run.seed))
     shards = int(np.prod(list(cfg["mesh"].values()))) if cfg.get("mesh") else 1
-    return reference.replay(run.seed, cfg, run.replay_steps(warm), quant=quant,
-                            fault=fault, shards=shards, device=device)
+    return model.replay(run.seed, cfg, run.replay_steps(warm), quant=quant,
+                        fault=fault, shards=shards, device=device)
 
 
 def compared(run: FedRun, program: Dict, ref: Dict) -> Dict[str, float]:
     """Every number `correct` compares, by name."""
     numbers = check.training_gaps(program, ref, CHECKED)
-    numbers["feed_faults"] = float(len(check.feed_faults(run.fed, run.cfg)))
+    numbers["feed_faults"] = float(len(arch(run.cfg).feed_faults(run.fed, run.cfg)))
     numbers["window_nonfinite"] = float(run.nonfinite)
     return numbers

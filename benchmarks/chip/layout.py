@@ -5,7 +5,11 @@ configuration and traffic. Each lives in a file of its own beside this
 module, so that a later change adds a cell, a configuration, a traffic
 mix or a metric by adding files and edits none:
 
-  configs/<config>.json     the model's sizes, optimizer and mesh as run
+  configs/<config>.json     the model's sizes, optimizer and mesh as run;
+                            its "model" names its architecture
+  archs/<model>.py          the architecture: its trainer, record spec,
+                            seeded weights, reference replay, operation
+                            and byte counts, batch checks and scope names
   traffic/<traffic>.json    the feed's parameters: records, pipeline
                             cores, the tuner's worker budget and cadence
   limits/<cell>.json        the limit of each number `correct` compares
@@ -18,6 +22,8 @@ import importlib.util
 import json
 import os
 import re
+import sys
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -104,3 +110,33 @@ def reader(metric: str) -> Callable[[Dict], Optional[float]]:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def arch(model: str) -> ModuleType:
+    """The module `archs/<model>.py`, loaded once per process. It gives:
+
+      SCOPES                      the train step's named scopes
+      SPANS                       (optional) host spans the model adds
+      make_trainer(cfg, mesh)     the program's trainer
+      record_spec(cfg, traffic, seed)   the records the pipeline makes
+      init_params(words, cfg, dtype)    the seeded weights, program layout
+      replay(seed, cfg, steps, *, quant, fault, shards, device)
+                                  the reference over the checked steps
+      train_flops_per_example(cfg)
+      scope_work(cfg)             {scope: {"ops", "bytes"}} a step needs
+      feed_faults(batches, cfg)   what the fed batches break
+    """
+    key = "chip_arch:" + _check_name(model)
+    if key not in sys.modules:
+        path = os.path.join(HERE, "archs", model + ".py")
+        if not os.path.exists(path):
+            raise LayoutError(f"no architecture archs/{model}.py")
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[key]
+            raise
+    return sys.modules[key]

@@ -6,7 +6,6 @@ innermost operation's scope (`spans.py`)."""
 
 
 def read(rec):
-    import spans
-    red = None if rec["trace"] is None else spans.window()
+    red = rec["spans"]
     ms = red and red["scope_ms"].get("embedding_lookup")
     return ms or None

@@ -5,7 +5,6 @@ to the innermost operation's scope (`spans.py`)."""
 
 
 def read(rec):
-    import spans
-    red = None if rec["trace"] is None else spans.window()
+    red = rec["spans"]
     ms = red and red["scope_ms"].get("table_update")
     return ms or None

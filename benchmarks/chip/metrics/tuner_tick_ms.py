@@ -1,10 +1,10 @@
 """Host time of one tuner tick: the program's `tuner_tick` span
 (`Session.step()` in the served loop, `repro.common.tracing`) on the
 loop's thread in the traced window, seconds over count (`spans.py`)."""
+import spans
 
 
 def read(rec):
-    import spans
-    red = None if rec["trace"] is None else spans.window()
+    red = rec["spans"]
     t = red and red["spans"].get("tuner_tick")
     return 1e3 * spans.per_tick(red, t["s"]) if t else None

@@ -1,11 +1,11 @@
 """Pipeline worker processes started per tuner tick: the program's
 `workers_started` counter's increase in the traced window (marked on
 the trace at each start) over the window's ticks (`spans.py`)."""
+import spans
 
 
 def read(rec):
-    import spans
-    red = None if rec["trace"] is None else spans.window()
+    red = rec["spans"]
     if red is None:
         return None
     return spans.per_tick(red, red["counters"].get("workers_started", 0))

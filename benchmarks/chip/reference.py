@@ -1,26 +1,24 @@
-"""Plain float32 DLRM reference, written apart from the program.
+"""The benchmark's plain float32 reference: what every architecture's
+module under `archs/` builds its reference from.
 
-Nothing here imports the program or takes anything it made. The
-weights are drawn from the run's seed by a counter-based hash, so any
+Nothing here imports the program or takes anything it made. Weights are
+drawn from the run's seed by a counter-based hash (`draw`), so any
 single element, or any row of a table, can be drawn on its own: the
 harness puts the whole drawn parameter tree into the trainer, and the
 reference draws again only the table rows that the checked batches look
-up. Everything else follows the published DLRM description (Naumov et
-al., arXiv:1906.00091) and the optimizer the configuration states:
+up.
 
-  bottom MLP (ReLU after every layer) -> one row per table per example,
-  summed over the bag -> the 351 lower-triangle pairwise dots of the 27
-  feature vectors -> top MLP (ReLU between layers) -> logit -> mean log
-  loss; row-wise Adagrad (one accumulator per table row, the mean of
-  the squared gradient over the row) on tables of more than
-  `rowwise_min_elems` elements and element-wise Adagrad on the MLPs (and
-  on smaller tables), with a linear warm-up then cosine learning rate.
-
-The reference keeps the tables sparse: `rows` holds only the distinct
-(table, id) rows that the replayed batches touch, in float32, plus one
-padding row. A row no batch touches has a zero gradient, so Adagrad
-leaves it and its accumulator as they are: the sparse replay is the
-dense one restricted to the rows that move.
+`sparse_replay` trains on the sparse state: `rows` holds only the
+distinct (table, id) rows that the replayed batches touch, in float32,
+plus one padding row. A row no batch touches has a zero gradient, so
+Adagrad leaves it and its accumulator as they are: the sparse replay is
+the dense one restricted to the rows that move. The optimizer is the one
+the configuration states: row-wise Adagrad (one accumulator per table
+row, the mean of the squared gradient over the row) on tables the
+architecture says are row-wise, element-wise Adagrad on everything else,
+with a linear warm-up then cosine learning rate. An architecture brings
+its table sizes, where each looked-up id lives, how a row is drawn, its
+dense weights and its forward pass; `archs/dlrm.py` shows the binding.
 
 `quant` puts a lower precision in the reference's place (the control):
 every parameter, activation and gathered row is rounded through it
@@ -45,9 +43,7 @@ _M1, _M2, _M3 = 0x7FEB352D, 0x846CA68B, 0x85EBCA6B
 # inside a fusion, and the chip's compiler does, so a draw recomputed
 # inside a reduction would not equal the same draw stored in bf16.
 EXACT = {"xla_allow_excess_precision": False}
-TABLE_LEAF = 0
-BOTTOM_LEAF = 10      # + layer index
-TOP_LEAF = 20         # + layer index
+TABLES = "tables"     # the parameter leaf that holds every table's rows
 
 
 # ------------------------------------------------------------ weights ----
@@ -76,7 +72,7 @@ def _bits(words, leaf: int, i, j, k):
     return _mix(h + u32(k))
 
 
-def _draw(words, leaf, i, j, k, scale, dtype):
+def draw(words, leaf, i, j, k, scale, dtype):
     """Uniform values of standard deviation `scale`, rounded once to
     `dtype`. (h >> 9) | 1.0's exponent is exactly a float in [1, 2), so
     each value is one rounding of an exact number: a draw of one element
@@ -87,50 +83,20 @@ def _draw(words, leaf, i, j, k, scale, dtype):
     return (f * jnp.float32(2.0 * math.sqrt(3.0) * scale)).astype(dtype)
 
 
-def _mlp(words, dims: Sequence[int], leaf0: int, dtype):
+def draw_mlp(words, dims: Sequence[int], leaf0: int, dtype):
+    """Dense layers dims[0] -> dims[1] -> ...: each {w, b}, w drawn at
+    standard deviation fan_in^-1/2 under leaf `leaf0` + its index, b 0."""
     layers = []
     for n, (a, b) in enumerate(zip(dims[:-1], dims[1:])):
         r = jax.lax.broadcasted_iota(jnp.uint32, (a, b), 0)
         c = jax.lax.broadcasted_iota(jnp.uint32, (a, b), 1)
-        layers.append({"w": _draw(words, leaf0 + n, 0, r, c, a ** -0.5, dtype),
+        layers.append({"w": draw(words, leaf0 + n, 0, r, c, a ** -0.5, dtype),
                        "b": jnp.zeros((b,), dtype)})
     return tuple(layers)
 
 
-def top_in(sizes: Dict) -> int:
-    f = sizes["n_sparse"] + 1
-    return f * (f - 1) // 2 + sizes["bottom_mlp"][-1]
-
-
-def init_mlps(words, sizes: Dict, dtype) -> Dict:
-    return {"bottom": _mlp(words, [sizes["n_dense"]] + list(sizes["bottom_mlp"]),
-                           BOTTOM_LEAF, dtype),
-            "top": _mlp(words, [top_in(sizes)] + list(sizes["top_mlp"]),
-                        TOP_LEAF, dtype)}
-
-
-def init_params(words, sizes: Dict, dtype):
-    """The whole parameter tree, in the program's layout: tables
-    (n_sparse, rows, embed_dim) and the two MLPs as tuples of {w, b}."""
-    shape = (sizes["n_sparse"], sizes["rows_per_table"], sizes["embed_dim"])
-    it = [jax.lax.broadcasted_iota(jnp.uint32, shape, a) for a in range(3)]
-    tables = _draw(words, TABLE_LEAF, *it, sizes["embed_dim"] ** -0.5, dtype)
-    return {"tables": tables, **init_mlps(words, sizes, dtype)}
-
-
-def table_rows(words, keys, sizes: Dict, dtype):
-    """Rows (U, embed_dim) of the drawn tables at flat keys
-    table * rows_per_table + id, as `init_params` draws them."""
-    v, d = sizes["rows_per_table"], sizes["embed_dim"]
-    keys = jnp.asarray(keys, jnp.int32)      # n_sparse * rows < 2^31 here
-    f, r = keys // v, keys % v
-    k = jnp.arange(d, dtype=jnp.uint32)
-    return _draw(words, TABLE_LEAF, f[:, None], r[:, None], k[None, :],
-                 d ** -0.5, dtype)
-
-
 # ------------------------------------------------------------- forward ---
-def _identity(x):
+def identity(x):
     return x
 
 
@@ -146,24 +112,6 @@ def _rounder(dtype):
 # the control's precision: the nearest below the one the configuration
 # states for its parameters
 CONTROL = {"float32": "bfloat16", "bfloat16": "float8_e4m3fn"}
-
-
-def forward(mlp, rows, dense, q: Callable = _identity):
-    """rows (B, F, hot, D) float32 -> logits (B,)."""
-    x = q(dense)
-    for layer in mlp["bottom"]:
-        x = jax.nn.relu(q(x) @ q(layer["w"]) + q(layer["b"]))
-    feats = jnp.concatenate([x[:, None], q(rows).sum(2)], axis=1)
-    feats = q(feats)
-    gram = jnp.einsum("bfd,bgd->bfg", feats, feats)
-    ii, jj = np.tril_indices(feats.shape[1], k=-1)
-    z = jnp.concatenate([gram[:, ii, jj], x], axis=-1)
-    top = mlp["top"]
-    for n, layer in enumerate(top):
-        z = q(z) @ q(layer["w"]) + q(layer["b"])
-        if n < len(top) - 1:
-            z = jax.nn.relu(z)
-    return z[:, 0]
 
 
 def log_loss(z, y):
@@ -183,46 +131,47 @@ def learning_rate(opt: Dict, step):
 # -------------------------------------------------------------- replay ---
 
 
-def _step(state, inv, dense, label, keep, lr, *, eps, rowwise, q, fault):
+def _step(state, inv, dense, label, keep, lr, *, cfg, rowwise, q, fault,
+          forward, altered):
     """One reference train step on the sparse state.
 
-    inv (B, F, hot) indexes `state["tables"]`; keep (U+1,) zeroes looked
+    inv (B, ...) indexes `state["tables"]`; keep (U+1,) zeroes looked
     up rows (all ones but under the `exchange_left_out` fault)."""
     def loss_of(params):
-        rows = params["tables"][inv] * keep[inv][..., None]
-        z = forward(params, rows, dense, q)
+        rows = params[TABLES][inv] * keep[inv][..., None]
+        z = forward(params, rows, dense, cfg, q)
         if fault == "half_batch":
             h = z.shape[0] // 2
             return log_loss(z[:h], label[:h])
         return log_loss(z, label)
 
-    params = {k: state[k] for k in ("tables", "bottom", "top")}
+    params = {k: v for k, v in state.items() if k != "acc"}
+    dense_keys = [k for k in params if k != TABLES]
     with jax.default_matmul_precision("highest"):
         loss, grads = jax.value_and_grad(loss_of)(params)
     if fault == "grad_altered":
-        top = list(grads["top"])
-        top[0] = {**top[0], "w": top[0]["w"] * 2.0}
-        grads = {**grads, "top": tuple(top)}
-    acc = state["acc"]
-    g_tab = grads["tables"]
+        grads = jax.tree_util.tree_map_with_path(
+            lambda path, g: g * 2.0 if leaf_name(path) == altered else g, grads)
+    acc, eps = state["acc"], float(cfg["eps"])
+    g_tab = grads[TABLES]
     if rowwise:
-        acc_tab = acc["tables"] + jnp.mean(jnp.square(g_tab), axis=-1)
+        acc_tab = acc[TABLES] + jnp.mean(jnp.square(g_tab), axis=-1)
         scale = jax.lax.rsqrt(acc_tab + eps)[:, None]
     else:
-        acc_tab = acc["tables"] + jnp.square(g_tab)
+        acc_tab = acc[TABLES] + jnp.square(g_tab)
         scale = jax.lax.rsqrt(acc_tab + eps)
-    tables = state["tables"] - lr * g_tab * scale
-    acc_mlp = jax.tree_util.tree_map(lambda a, g: a + jnp.square(g),
-                                     {"bottom": acc["bottom"], "top": acc["top"]},
-                                     {"bottom": grads["bottom"], "top": grads["top"]})
-    mlp = jax.tree_util.tree_map(
+    tables = state[TABLES] - lr * g_tab * scale
+    acc_dense = jax.tree_util.tree_map(lambda a, g: a + jnp.square(g),
+                                       {k: acc[k] for k in dense_keys},
+                                       {k: grads[k] for k in dense_keys})
+    weights = jax.tree_util.tree_map(
         lambda p, g, a: p - lr * g * jax.lax.rsqrt(a + eps),
-        {"bottom": state["bottom"], "top": state["top"]},
-        {"bottom": grads["bottom"], "top": grads["top"]}, acc_mlp)
-    if q is not _identity:
+        {k: state[k] for k in dense_keys},
+        {k: grads[k] for k in dense_keys}, acc_dense)
+    if q is not identity:
         tables = q(tables)
-        mlp = jax.tree_util.tree_map(q, mlp)
-    new = {"tables": tables, **mlp, "acc": {"tables": acc_tab, **acc_mlp}}
+        weights = jax.tree_util.tree_map(q, weights)
+    new = {TABLES: tables, **weights, "acc": {TABLES: acc_tab, **acc_dense}}
     return new, loss, leaf_norms(grads)
 
 
@@ -240,89 +189,113 @@ def leaf_name(path) -> str:
 
 
 @functools.lru_cache(maxsize=None)
-def _compiled_step(eps: float, rowwise: bool, quant: str, fault: str):
-    q = _identity if quant == "float32" else _rounder(jnp.dtype(quant))
-    return jax.jit(functools.partial(_step, eps=eps, rowwise=rowwise, q=q,
-                                     fault=fault), donate_argnums=(0,),
-                   compiler_options=EXACT)
+def _compiled_step(cfg: "_Frozen", rowwise: bool, quant: str, fault: str,
+                   forward: Callable, altered: str):
+    q = identity if quant == "float32" else _rounder(jnp.dtype(quant))
+    return jax.jit(functools.partial(_step, cfg=cfg, rowwise=rowwise, q=q,
+                                     fault=fault, forward=forward,
+                                     altered=altered),
+                   donate_argnums=(0,), compiler_options=EXACT)
 
 
-def _rows0(words, keys, n, sizes, dtype):
-    """The drawn rows at `keys` in float32, zero past the first n and in
-    the padding row."""
-    rows = table_rows(words, keys, sizes, dtype).astype(jnp.float32)
-    valid = jnp.arange(keys.shape[0]) < n
+def _rows0(words, table, row, n, draw_rows, cfg, dtype):
+    """The drawn rows at (table, row) in float32, zero past the first n
+    and in the padding row."""
+    rows = draw_rows(words, table, row, cfg, dtype).astype(jnp.float32)
+    valid = jnp.arange(table.shape[0]) < n
     return jnp.concatenate([jnp.where(valid[:, None], rows, 0.0),
                             jnp.zeros((1, rows.shape[1]), jnp.float32)])
 
 
-def _table_change(rows, words, keys, n, sizes, dtype):
-    return jnp.sqrt(jnp.sum(jnp.square(rows - _rows0(words, keys, n, sizes, dtype))))
+def _table_change(rows, words, table, row, n, draw_rows, cfg, dtype):
+    return jnp.sqrt(jnp.sum(jnp.square(
+        rows - _rows0(words, table, row, n, draw_rows, cfg, dtype))))
 
 
-def replay(seed: int, cfg: Dict, steps: Sequence[Tuple[int, Dict]], *,
-           quant: str = "float32", fault: str = "", shards: int = 1,
-           device=None) -> Dict:
+def sparse_replay(seed: int, cfg: Dict, steps: Sequence[Tuple[int, Dict]], *,
+                  rows: Sequence[int], lookups: Callable, draw_rows: Callable,
+                  init_dense: Callable, forward: Callable, altered: str,
+                  rowwise: bool, quant: str = "float32", fault: str = "",
+                  shards: int = 1, device=None) -> Dict:
     """Replay `steps` [(optimizer step index, host batch), ...] from the
-    weights the seed draws for configuration `cfg` (its sizes, optimizer
-    and parameter dtype); returns per step its loss and per-leaf gradient
+    weights the seed draws for configuration `cfg` (its optimizer and
+    parameter dtype); returns per step its loss and per-leaf gradient
     norms, and per leaf the norm of the parameters' change.
 
-    `exchange_left_out` zeroes the rows that a (data, model) row-sharded
-    lookup would have to fetch from the other `model` rank: the ids in
-    row shards of odd index, of `shards` equal shards."""
-    sizes = opt = cfg
+    The architecture brings:
+      rows        each table's row count; the tables lie one after
+                  another in the parameter leaf `tables`;
+      lookups     batch -> (ids (B, ...) int, table (...) int): each
+                  looked-up id and the table it indexes;
+      draw_rows   (words, table (U,), row (U,), cfg, dtype) -> (U, D):
+                  the rows as the architecture's weights draw them;
+      init_dense  (words, cfg, dtype) -> the other parameters, a dict;
+      forward     (params, rows (B, ..., D) float32, dense, cfg, q) ->
+                  logits (B,), where rows are those `lookups` names, in
+                  its shape, and q rounds (the control);
+      altered     the leaf whose gradient `grad_altered` doubles;
+      rowwise     whether the tables' Adagrad is row-wise.
+    `forward` and `draw_rows` are module-level functions: the compiled
+    step is kept by them.
+
+    Faults: `half_batch` takes the loss over the first half of the batch;
+    `grad_altered` doubles one gradient where the backward produces it;
+    `exchange_left_out` zeroes the rows that a row-sharded lookup would
+    fetch from the other `model` rank: the ids in row shards of odd
+    index, of `shards` equal shards of their table."""
     dtype = jnp.dtype(cfg["param_dtype"])
-    v = sizes["rows_per_table"]
+    sizes = np.asarray(rows, np.int64)
+    offsets = np.concatenate([[0], np.cumsum(sizes)[:-1]])
     distinct: List[Dict] = []
     for _, b in steps:
         if not any(b is d for d in distinct):
             distinct.append(b)
-    ids = [np.asarray(b["sparse_ids"], np.int64) for b in distinct]
-    offs = (np.arange(sizes["n_sparse"], dtype=np.int64) * v)[None, :, None]
-    keys, inv = np.unique(np.concatenate([(i + offs).reshape(-1) for i in ids]),
+    flat = []
+    for b in distinct:
+        ids, table = lookups(b)
+        flat.append(np.asarray(ids, np.int64) + offsets[table])
+    keys, inv = np.unique(np.concatenate([f.reshape(-1) for f in flat]),
                           return_inverse=True)
-    bounds = np.cumsum([0] + [i.size for i in ids])
+    table = np.searchsorted(offsets, keys, side="right") - 1
+    row = keys - offsets[table]
+    bounds = np.cumsum([0] + [f.size for f in flat])
     # fixed size: one compiled program whatever the overlap of the batches
     cap = int(bounds[-1])
     keep = np.ones(cap + 1, np.float32)
     if fault == "exchange_left_out":
-        keep[:keys.size] = (((keys % v) // (v // shards)) % 2 == 0)
+        keep[:keys.size] = (row // (sizes[table] // shards)) % 2 == 0
     put = functools.partial(jax.device_put, device=device)
     words = put(seed_words(seed))
-    # int32 keys: table * rows + id stays below 2^31 at these sizes
-    keys_d = put(np.concatenate([keys, np.zeros(cap - keys.size, np.int64)]
-                                ).astype(np.int32))
-    rows0 = jax.jit(_rows0, static_argnums=(3, 4), compiler_options=EXACT)(
-        words, keys_d, keys.size, _Frozen(sizes), dtype)
-    mlp0 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
-                                  jax.jit(lambda w: init_mlps(w, sizes, dtype),
-                                          compiler_options=EXACT)(words))
+    pad = lambda a: put(np.concatenate([a, np.zeros(cap - a.size, np.int64)]
+                                       ).astype(np.int32))
+    table_d, row_d = pad(table), pad(row)
+    frozen = _Frozen(cfg)
+    rows0 = jax.jit(_rows0, static_argnums=(4, 5, 6), compiler_options=EXACT)(
+        words, table_d, row_d, keys.size, draw_rows, frozen, dtype)
+    dense0 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                    jax.jit(lambda w: init_dense(w, cfg, dtype),
+                                            compiler_options=EXACT)(words))
     zeros = lambda t: jax.tree_util.tree_map(jnp.zeros_like, t)
-    # row-wise Adagrad serves a table of more than rowwise_min_elems
-    # elements; a smaller one is element-wise like the MLPs
-    rowwise = (sizes["n_sparse"] * v * sizes["embed_dim"]
-               > opt["rowwise_min_elems"])
-    acc_shape = (cap + 1,) if rowwise else (cap + 1, sizes["embed_dim"])
-    state = {"tables": rows0, **jax.tree_util.tree_map(jnp.copy, mlp0),
-             "acc": {"tables": jnp.zeros(acc_shape, jnp.float32), **zeros(mlp0)}}
-    fn = _compiled_step(float(opt["eps"]), rowwise, quant, fault)
+    acc_shape = (cap + 1,) if rowwise else (cap + 1, rows0.shape[1])
+    state = {TABLES: rows0, **jax.tree_util.tree_map(jnp.copy, dense0),
+             "acc": {TABLES: jnp.zeros(acc_shape, jnp.float32), **zeros(dense0)}}
+    fn = _compiled_step(frozen, rowwise, quant, fault, forward, altered)
     keep_d = put(keep)
-    invs = [put(inv[bounds[k]:bounds[k + 1]].reshape(i.shape).astype(np.int32))
-            for k, i in enumerate(ids)]
+    invs = [put(inv[bounds[k]:bounds[k + 1]].reshape(f.shape).astype(np.int32))
+            for k, f in enumerate(flat)]
     losses, grads = [], []
     for step, batch in steps:
         k = next(n for n, d in enumerate(distinct) if d is batch)
         state, loss, g = fn(state, invs[k], put(np.asarray(batch["dense"], np.float32)),
                             put(np.asarray(batch["label"], np.float32)), keep_d,
-                            learning_rate(opt, step))
+                            learning_rate(cfg, step))
         losses.append(loss)
         grads.append(g)
     change = leaf_norms(jax.tree_util.tree_map(
-        lambda a, b: a - b, {"bottom": state["bottom"], "top": state["top"]}, mlp0))
-    change["tables"] = jax.jit(_table_change, static_argnums=(4, 5),
-                               compiler_options=EXACT)(
-        state["tables"], words, keys_d, keys.size, _Frozen(sizes), dtype)
+        lambda a, b: a - b, {k: state[k] for k in dense0}, dense0))
+    change[TABLES] = jax.jit(_table_change, static_argnums=(5, 6, 7),
+                             compiler_options=EXACT)(
+        state[TABLES], words, table_d, row_d, keys.size, draw_rows, frozen, dtype)
     out = jax.device_get({"loss": losses, "grad": grads, "change": change})
     return {"loss": [float(x) for x in out["loss"]],
             "grad": [{k: float(x) for k, x in g.items()} for g in out["grad"]],

@@ -8,7 +8,9 @@ asks for in BENCHMARK.json. Set-up draws the weights from the seed,
 trains the first fed steps through the served loop, and warms every
 program the window uses; the window then trains for `--seconds` through
 the same loop; after it, the program's state is freed and the float32
-reference replays the checked steps. The last line of standard output is
+reference replays the checked steps. What is particular to the cell's
+model comes from its architecture, `archs/<model>.py` (`layout.arch`).
+The last line of standard output is
 one JSON object (`correct`, `attempted`, `failed`, `metrics`, `device`,
 with `--trace 1` also `breakdown`, and last `checks`: each number
 compared with its limit), and the last lines of standard error list the
@@ -84,19 +86,24 @@ def measure(argv=None, *, devices=None, cell=None) -> dict:
 
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    # a compiled step's op_names (its scopes) are part of its cache key:
+    # a warm cache never serves another commit's scopes
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     devices = devices if devices is not None else require_tpu(cell["chips"])
     kind = devices[0].device_kind
     peaks = layout.peaks(kind) if devices[0].platform == "tpu" else None
 
-    import flops
     import harness
+    import spans
     import trace as trace_mod
+    cfg = cell["config"]
+    arch = layout.arch(cfg["model"])
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda event, secs, **kw: compiles.append(time.perf_counter())
         if event == "/jax/core/compile/backend_compile_duration" else None)
     trace_dir = os.path.join(HERE, "out", "trace") if args.trace else None
-    trainer = harness.make_trainer(cell["config"], devices)
+    trainer = harness.make_trainer(cfg, devices)
     run = harness.drive(trainer, cell, args.seed, args.seconds, trace_dir)
     if run.t1 is None:
         sys.exit("run.py: the window never closed")
@@ -116,20 +123,22 @@ def measure(argv=None, *, devices=None, cell=None) -> dict:
     window_s = run.t1 - run.t0
     record = {
         "setup_s": run.t0 - _T0, "window_s": window_s, "steps": run.steps,
-        "batch": cell["config"]["batch"], "chips": cell["chips"],
-        "examples": run.steps * cell["config"]["batch"],
+        "batch": cfg["batch"], "chips": cell["chips"],
+        "examples": run.steps * cfg["batch"],
         "feed_wait_s": run.feed_wait_s,
         "pipeline_cpu_s": (run.cpu1 - run.cpu0
                            if run.cpu0 is not None and run.cpu1 is not None else None),
         "peak_bytes": peak, "peaks": peaks,
-        "flops_per_example": flops.train_flops_per_example(cell["config"]),
-        "trace": None,
+        "flops_per_example": arch.train_flops_per_example(cfg),
+        "config": cfg, "scope_work": arch.scope_work(cfg),
+        "trace": None, "spans": None,
     }
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(devices), "memory_peak_bytes": peak}
     out = {"correct": correct, "attempted": run.steps, "failed": run.nonfinite}
     if args.trace:
         record["trace"] = trace_mod.reduce(trace_mod.extract(trace_dir))
+        record["spans"] = spans.window(trace_dir, arch)
         device.update(busy_s=record["trace"]["busy_s"],
                       window_s=record["trace"]["window_s"])
         out["breakdown"] = {"device_ops": record["trace"]["device_ops"],

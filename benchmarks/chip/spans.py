@@ -5,9 +5,14 @@ reads what the program itself writes into the same profiler trace:
 
   host    the spans and counters of `repro.common.tracing` and the
           loop's `StepTraceAnnotation` ("train"), on the loop's thread:
-          the host line that holds the harness's `bench_window`;
+          the host line that holds the harness's `bench_window`. The
+          served loop's and the tuner tick's span names are every
+          model's (`SPANS`); a model's own host spans come from its
+          architecture (`archs/<model>.SPANS`). Every other host event
+          that carries an `n` stat is a counter (`tracing.count`);
   device  every operation of a `jit_train_step` call, put down to the
-          innermost `jax.named_scope` of its instruction. The v5e's op
+          innermost `jax.named_scope` of its instruction, of those the
+          traced cell's architecture names (`archs/<model>.SCOPES`). The v5e's op
           events carry no metadata, so the scope comes from
           `metadata={op_name=...}` in the compiled module, which the
           trace's `/host:metadata` plane holds as a serialized HloProto
@@ -22,9 +27,9 @@ operation, so the parts add up to the whole.
 
 `extract` reads the `.xplane.pb` into plain lists, `reduce` works on
 those, so the reduction can be checked without a chip; `window()` does
-both once per trace for the metric readers.
+both for `run.py`, which hands the result to the metric readers.
 
-    python3 benchmarks/chip/spans.py [trace_dir]   # prints the reduction
+    python3 benchmarks/chip/spans.py [trace_dir [model]]   # prints the reduction
 """
 from __future__ import annotations
 
@@ -48,14 +53,17 @@ LOOP_SPANS = ("feed_wait", "step_dispatch", "host_loop", "loss_read",
 TICK_SPANS = ("tick_measure", "tick_observe", "tick_propose", "tick_apply",
               "pipe_stats", "rss_scan", "worker_start", "worker_retire")
 SPANS = LOOP_SPANS + TICK_SPANS
-COUNTERS = ("workers_started", "workers_retired", "rss_pids_read")
-SCOPES = ("bottom_mlp", "embedding_lookup", "interaction", "top_mlp",
-          "optimizer_update", "table_update", "dense_update")
 _DEVICE = re.compile(r"^/device:TPU:(\d+)$")
 _INSTR = re.compile(r"^%(\S+) = ")
-# a scope as one part of an op_name: "/embedding_lookup/" and the
-# transformed "jvp(embedding_lookup)" both count
-_SCOPE = re.compile(r"(?:^|[/(])(" + "|".join(SCOPES) + r")(?=$|[/)])")
+
+
+def scope_pattern(scopes: Sequence[str]) -> "re.Pattern":
+    """A scope as one part of an op_name: "/embedding_lookup/" and the
+    transformed "jvp(embedding_lookup)" both count."""
+    if not scopes:
+        return re.compile(r"(?!)")
+    return re.compile(r"(?:^|[/(])(" + "|".join(map(re.escape, scopes))
+                      + r")(?=$|[/)])")
 
 Event = Tuple[str, float, float]
 
@@ -116,7 +124,8 @@ def _ids(buf: bytes, values: List) -> List[int]:
     return out
 
 
-def module_scopes(buf: bytes, hlo_proto: Tuple[int, int]) -> Dict[str, str]:
+def module_scopes(buf: bytes, hlo_proto: Tuple[int, int],
+                  pattern: "re.Pattern") -> Dict[str, str]:
     """{instruction name: innermost scope} of one serialized HloProto
     (HloProto.hlo_module = 1; HloModuleProto.computations = 3;
     HloComputationProto name 1, instructions 2, id 5; HloInstructionProto
@@ -133,7 +142,7 @@ def module_scopes(buf: bytes, hlo_proto: Tuple[int, int]) -> Dict[str, str]:
             names.append(name)
             meta = _sub(buf, ins, 7)
             op_name = _sub(buf, meta[0], 2) if meta else []
-            found = _SCOPE.findall(_str(buf, op_name[0])) if op_name else []
+            found = pattern.findall(_str(buf, op_name[0])) if op_name else []
             if found:
                 own[name] = found[-1]
             calls[name] = _ids(buf, _sub(buf, ins, 38))
@@ -160,7 +169,7 @@ def module_scopes(buf: bytes, hlo_proto: Tuple[int, int]) -> Dict[str, str]:
     return out
 
 
-def hlo_scopes(path: str) -> Dict[str, Dict[str, str]]:
+def hlo_scopes(path: str, scopes: Sequence[str]) -> Dict[str, Dict[str, str]]:
     """{module name: module_scopes} of every train-step module in the
     trace's metadata plane (XSpace.planes = 1; XPlane name 2,
     event_metadata 4 as map entries of key 1 and value 2; XEventMetadata
@@ -168,6 +177,7 @@ def hlo_scopes(path: str) -> Dict[str, Dict[str, str]]:
     with open(path, "rb") as fh:
         buf = fh.read()
     out = {}
+    pattern = scope_pattern(scopes)
     for field, plane in _fields(buf):
         if field != 1:
             continue
@@ -182,24 +192,27 @@ def hlo_scopes(path: str) -> Dict[str, Dict[str, str]]:
                     continue
                 for stat in _sub(buf, meta, 5):
                     for blob in _sub(buf, stat, 6):
-                        out[mod] = module_scopes(buf, blob)
+                        out[mod] = module_scopes(buf, blob, pattern)
     return out
 
 
 # -------------------------------------------------------------- extract --
-def extract(trace_dir: str) -> Dict:
+def extract(trace_dir: str, scopes: Sequence[str] = (),
+            spans: Sequence[str] = ()) -> Dict:
     """The newest trace under `trace_dir` as {"host": {line: [[name,
     start_ns, duration_ns, n], ...]}, "devices": {plane: {"ops":
     [[instruction, start_ns, duration_ns], ...], "modules": [...]}},
     "scopes": {module: {instruction: scope}}}; host events only of the
-    names read here, `n` a counter's increase (1 for a span)."""
+    span names read here (`SPANS` and the model's `spans`) and the
+    counters (events with an `n` stat), `n` a counter's increase (1 for
+    a span); `scopes` the model's named scopes."""
     from jax.profiler import ProfileData
     paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     data = ProfileData.from_file(paths[-1])
-    wanted = set(SPANS) | {WINDOW, STEP}
+    wanted = set(SPANS) | set(spans) | {WINDOW, STEP}
     host: Dict[str, List] = {}
     devices: Dict[str, Dict[str, List]] = {}
     for plane in data.planes:
@@ -220,12 +233,14 @@ def extract(trace_dir: str) -> Dict:
                 for e in line.events:
                     if e.name in wanted:
                         kept.append([e.name, float(e.start_ns), float(e.duration_ns), 1])
-                    elif e.name in COUNTERS:
-                        n = dict(e.stats).get("n", 0)
-                        kept.append([e.name, float(e.start_ns), 0.0, int(n)])
+                        continue
+                    n = dict(e.stats).get("n")
+                    if isinstance(n, int):
+                        kept.append([e.name, float(e.start_ns), 0.0, n])
                 if kept:
                     host[f"{plane.name}#{k}:{line.name}"] = kept
-    return {"host": host, "devices": devices, "scopes": hlo_scopes(paths[-1])}
+    return {"host": host, "devices": devices,
+            "scopes": hlo_scopes(paths[-1], scopes)}
 
 
 # --------------------------------------------------------------- reduce --
@@ -296,10 +311,10 @@ def _pieces_in(pieces, starts, s: float, e: float):
 
 
 def _host_reduce(lines: Dict[str, List], w0: float, w1: float, loop: str,
-                 first_ops: Sequence[Event], top: int) -> Dict:
+                 first_ops: Sequence[Event], top: int, names: set) -> Dict:
     inside = lambda ev: ev[1] >= w0 and ev[1] + ev[2] <= w1
     spans = _last_of_name([tuple(ev[:3]) for ev in lines[loop]
-                           if ev[0] in SPANS and inside(ev)])
+                           if ev[0] in names and inside(ev)])
     table: Dict[str, Dict[str, float]] = {}
     for name, _, d in spans:
         t = table.setdefault(name, {"n": 0, "s": 0.0})
@@ -309,10 +324,11 @@ def _host_reduce(lines: Dict[str, List], w0: float, w1: float, loop: str,
     elsewhere: Dict[str, float] = defaultdict(float)
     for key, evs in lines.items():
         for name, s, d, n in evs:
-            if name in COUNTERS and w0 <= s <= w1:
+            if name in names or name in (WINDOW, STEP):
+                if key != loop and name in names and inside((name, s, d)):
+                    elsewhere[name] += d * 1e-9
+            elif w0 <= s <= w1:
                 counters[name] += n
-            elif key != loop and name in SPANS and inside((name, s, d)):
-                elsewhere[name] += d * 1e-9
     # the tick by part: its direct children, and its own time
     ticks = [ev for ev in spans if ev[0] == "tuner_tick"]
     split: Dict[str, float] = defaultdict(float)
@@ -386,11 +402,12 @@ def _device_reduce(devices: Dict, scopes: Dict[str, Dict[str, str]],
             "scope_ms": {k: v / calls * 1e-6 for k, v in sorted(total.items())}}
 
 
-def reduce(ev: Dict, top: int = 10) -> Dict:
-    """The window's program spans, counters, tick split and idle gaps
-    (host), and the train step's device time by scope. "program" is
-    false where the loop wrote no step annotation: a program without
-    these spans, whose numbers are then not read."""
+def reduce(ev: Dict, top: int = 10, spans: Sequence[str] = ()) -> Dict:
+    """The window's program spans (`SPANS` and the model's `spans`),
+    counters, tick split and idle gaps (host), and the train step's
+    device time by scope. "program" is false where the loop wrote no
+    step annotation: a program without these spans, whose numbers are
+    then not read."""
     lines = ev["host"]
     windows = [(key, e) for key, evs in lines.items() for e in evs if e[0] == WINDOW]
     if not windows or not ev["devices"]:
@@ -401,25 +418,21 @@ def reduce(ev: Dict, top: int = 10) -> Dict:
     out = {"window_s": wd * 1e-9,
            "program": any(e[0] == STEP for e in lines[loop])}
     out.update(_host_reduce(lines, w0, w1, loop,
-                            [tuple(o) for o in ev["devices"][first]["ops"]], top))
+                            [tuple(o) for o in ev["devices"][first]["ops"]], top,
+                            set(SPANS) | set(spans)))
     out.update(_device_reduce(ev["devices"], ev.get("scopes", {}), w0, w1))
     return out
 
 
-_CACHE: Dict[Tuple[str, float], Dict] = {}
-
-
-def window(trace_dir: str = TRACE_DIR) -> Optional[Dict]:
-    """`reduce` of the newest trace under `trace_dir`, read once; None
-    where there is no trace or the program wrote no spans into it."""
-    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
-    if not paths:
+def window(trace_dir: str = TRACE_DIR, arch=None) -> Optional[Dict]:
+    """`reduce` of the newest trace under `trace_dir`, with the scopes
+    and host spans of `arch` (an `archs/<model>` module); None where
+    there is no trace or the program wrote no spans into it."""
+    if not glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True):
         return None
-    newest = max(paths, key=os.path.getmtime)
-    key = (newest, os.path.getmtime(newest))
-    if key not in _CACHE:
-        _CACHE[key] = reduce(extract(trace_dir))
-    red = _CACHE[key]
+    scopes = getattr(arch, "SCOPES", ())
+    extra = getattr(arch, "SPANS", ())
+    red = reduce(extract(trace_dir, scopes, extra), spans=extra)
     return red if red["program"] else None
 
 
@@ -430,6 +443,25 @@ def per_tick(red: Optional[Dict], seconds: float) -> Optional[float]:
     return seconds / red["ticks"]
 
 
+def roofline_pct(rec: Dict, scope: str) -> Optional[float]:
+    """The share of its roofline that a scope's device time per step
+    reaches: the least time the chip could take for the operations and
+    bytes the architecture counts for it (`scope_work`), the larger of
+    ops over the bf16 peak and bytes over the HBM peak, over the scope's
+    measured time, in %. None where the scope was not measured or not
+    counted, or the device has no peaks."""
+    red, work, peaks = rec["spans"], rec["scope_work"].get(scope), rec["peaks"]
+    ms = red and red["scope_ms"].get(scope)
+    if not ms or work is None or peaks is None:
+        return None
+    least = max(work["ops"] / peaks["bf16_flops_per_s"],
+                work["bytes"] / peaks["hbm_bytes_per_s"])
+    return 100.0 * least / (ms * 1e-3)
+
+
 if __name__ == "__main__":
-    print(json.dumps(reduce(extract(sys.argv[1] if len(sys.argv) > 1 else TRACE_DIR)),
+    sys.path.insert(0, HERE)
+    import layout
+    model = layout.arch(sys.argv[2]) if len(sys.argv) > 2 else None
+    print(json.dumps(window(sys.argv[1] if len(sys.argv) > 1 else TRACE_DIR, model),
                      indent=1))

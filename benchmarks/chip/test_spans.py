@@ -4,10 +4,12 @@ counters and named scopes, that need no chip.
     JAX_PLATFORMS=cpu python -m pytest -q benchmarks/chip
 
 By hand: gaps go to the innermost span, operations to their scope, the
-parts add up to the whole, and the harness's copies of the loop's names
-are not counted. Then on a small trace recorded on a v5e (two steps and
-one tuner tick of a `dlrm-criteo-1chip.cores-all` window, the window
-span cut to them), and the HloProto decoder on a hand-encoded module.
+parts add up to the whole, the harness's copies of the loop's names are
+not counted, and every other host event with a count is a counter. Then
+on a small trace recorded on a v5e (two steps and one tuner tick of a
+`dlrm-criteo-1chip.cores-all` window, the window span cut to them), and
+the HloProto decoder on a hand-encoded module, with the scope names of
+the architecture it is given.
 """
 from __future__ import annotations
 
@@ -25,7 +27,17 @@ import layout  # noqa: E402
 import spans  # noqa: E402
 
 NEW = ("tuner_tick_ms", "tick_rss_scan_ms", "worker_starts_per_tick",
-       "embedding_lookup_device_ms", "table_update_device_ms")
+       "embedding_lookup_device_ms", "table_update_device_ms",
+       "ticks_outlasting_step_pct", "embedding_lookup_roofline",
+       "table_update_roofline")
+SCOPES = layout.arch("dlrm").SCOPES
+
+
+def _record(red) -> dict:
+    """What run.py hands the readers of a traced v5e run of the cell."""
+    cfg = layout.config("dlrm-criteo-1chip")
+    return {"trace": {}, "spans": red, "peaks": layout.peaks("TPU v5 lite"),
+            "config": cfg, "scope_work": layout.arch("dlrm").scope_work(cfg)}
 
 
 def _hand_trace() -> dict:
@@ -44,6 +56,7 @@ def _hand_trace() -> dict:
         ["tick_apply", 75, 15, 1], ["worker_start", 76, 12, 1],
         ["rss_scan", 85, 2, 1], ["workers_started", 88, 0, 1],
         ["rss_pids_read", 60, 0, 3], ["rss_pids_read", 86, 0, 1],
+        ["ticks_outlasting_step", 94, 0, 1],
     ]
     sampler = [["rss_scan", 20, 30, 1], ["rss_pids_read", 49, 0, 3]]
     ops = [["dot.1", 10, 30], ["gather.2", 12, 10], ["fusion.3", 25, 10],
@@ -65,7 +78,9 @@ def test_spans_reduction_by_hand():
     assert r["spans"]["tuner_tick"] == {"n": 1, "s": pytest.approx(45e-9)}
     assert r["spans"]["rss_scan"] == {"n": 2, "s": pytest.approx(12e-9)}
     assert r["spans_elsewhere_s"] == {"rss_scan": pytest.approx(30e-9)}
-    assert r["counters"] == {"rss_pids_read": 7, "workers_started": 1}
+    assert r["counters"] == {"rss_pids_read": 7, "workers_started": 1,
+                             "ticks_outlasting_step": 1}
+    assert layout.reader("ticks_outlasting_step_pct")(_record(r)) == 100.0
     # the tick: its direct children and its own time add up to it
     assert r["tick_split_s"] == {"tick_measure": pytest.approx(20e-9),
                                  "tick_apply": pytest.approx(15e-9),
@@ -98,12 +113,11 @@ def test_a_trace_without_the_programs_spans_reads_nothing(monkeypatch, tmp_path)
     path = tmp_path / "plugins" / "profile" / "x" / "h.xplane.pb"
     path.parent.mkdir(parents=True)
     path.write_bytes(b"")
-    monkeypatch.setattr(spans, "extract", lambda d: ev)
-    assert spans.window(str(tmp_path)) is None
-    monkeypatch.setattr(spans, "window", lambda: None)
+    monkeypatch.setattr(spans, "extract", lambda d, scopes, extra: ev)
+    assert spans.window(str(tmp_path), layout.arch("dlrm")) is None
     for name in NEW:
-        assert layout.reader(name)({"trace": {}}) is None
-        assert layout.reader(name)({"trace": None}) is None
+        assert layout.reader(name)(_record(None)) is None
+        assert layout.reader(name)({**_record(None), "trace": None}) is None
 
 
 def _recorded() -> dict:
@@ -112,7 +126,7 @@ def _recorded() -> dict:
         return json.load(fh)
 
 
-def test_spans_reduction_of_a_recorded_trace(monkeypatch):
+def test_spans_reduction_of_a_recorded_trace():
     r = spans.reduce(_recorded())
     assert r["program"] and r["steps"] == 2 and r["ticks"] == 1
     for name in ("feed_wait", "step_dispatch", "host_loop", "loss_read"):
@@ -135,13 +149,16 @@ def test_spans_reduction_of_a_recorded_trace(monkeypatch):
     assert r["scope_ms"]["unscoped"] < 0.1 * r["step_ms"]
     assert 0 < r["scope_ms"]["embedding_lookup"] + r["scope_ms"]["table_update"] \
         < r["step_ms"]
-    monkeypatch.setattr(spans, "window", lambda: r)
-    got = {name: layout.reader(name)({"trace": {}}) for name in NEW}
+    got = {name: layout.reader(name)(_record(r)) for name in NEW}
     assert got["tuner_tick_ms"] == pytest.approx(1e3 * r["tick_s"])
     assert got["worker_starts_per_tick"] == r["counters"]["workers_started"]
     assert got["embedding_lookup_device_ms"] == r["scope_ms"]["embedding_lookup"]
     assert got["table_update_device_ms"] == r["scope_ms"]["table_update"]
     assert 0 < got["tick_rss_scan_ms"] < got["tuner_tick_ms"]
+    # this trace predates the counter: no tick outlasted its step
+    assert got["ticks_outlasting_step_pct"] == 0.0
+    for name in ("embedding_lookup_roofline", "table_update_roofline"):
+        assert 0 < got[name] < 100, name
 
 
 def _window(ev):
@@ -196,7 +213,7 @@ def test_module_scopes_from_a_hand_encoded_hlo_proto(tmp_path):
                  (2, _instr("reduce.3", "jit(train_step)/optimizer_update/reduce_sum")),
                  (2, _instr("copy.5", "jit(train_step)/copy")))
     proto = _msg((1, _msg((1, "jit_train_step"), (3, entry), (3, fused))))
-    scopes = spans.module_scopes(proto, (0, len(proto)))
+    scopes = spans.module_scopes(proto, (0, len(proto)), spans.scope_pattern(SCOPES))
     assert scopes == {"fusion.4": "embedding_lookup", "while.2": "table_update",
                       "reduce.3": "optimizer_update",
                       "scatter-add.2": "embedding_lookup"}
@@ -206,4 +223,8 @@ def test_module_scopes_from_a_hand_encoded_hlo_proto(tmp_path):
     space = _msg((1, _msg((2, "/host:CPU"))), (1, plane))
     path = tmp_path / "h.xplane.pb"
     path.write_bytes(space)
-    assert spans.hlo_scopes(str(path)) == {"jit_train_step(5)": scopes}
+    assert spans.hlo_scopes(str(path), SCOPES) == {"jit_train_step(5)": scopes}
+    # another architecture's scopes are found by its names alone
+    assert spans.hlo_scopes(str(path), ("table_update", "scatter-add")) == {
+        "jit_train_step(5)": {"fusion.4": "scatter-add", "while.2": "table_update",
+                              "scatter-add.2": "scatter-add"}}
