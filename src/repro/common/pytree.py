@@ -44,9 +44,12 @@ def _sumsq(x) -> jax.Array:
 
     XLA CPU fails to fuse convert+square+reduce on multi-GiB bf16 tensors
     (observed 3 x 5 GiB f32 temp on the 1T-param config); chunking the
-    reduction over axis 0 bounds the transient at one slice.
+    reduction over axis 0 of a stacked (3-D or more) tensor bounds the
+    transient at one slice. A 2-D leaf is reduced whole: its slices are
+    single rows, and a scan over millions of rows (a packed embedding
+    table) is a device loop of millions of tiny steps.
     """
-    if x.ndim >= 2 and x.size > (1 << 26):
+    if x.ndim >= 3 and x.size > (1 << 26):
         def body(acc, sl):
             return acc + jnp.sum(jnp.square(sl.astype(jnp.float32))), None
         acc, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), x)

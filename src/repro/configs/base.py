@@ -193,11 +193,33 @@ class DLRMConfig:
     bottom_mlp: Tuple[int, ...] = (512, 256, 128)
     top_mlp: Tuple[int, ...] = (1024, 1024, 512, 256, 1)
     multi_hot: int = 1
+    # per-feature bag sizes (fixed-size multi-hot bags); empty: `multi_hot`
+    # for every feature
+    hot_sizes: Tuple[int, ...] = ()
+    # "dot": the pairwise dots of the feature vectors (DLRM); "dcn":
+    # `dcn_layers` low-rank cross layers of rank `dcn_rank` over their
+    # concatenation (DLRM-DCNv2)
+    interaction: str = "dot"
+    dcn_layers: int = 0
+    dcn_rank: int = 0
     param_dtype: str = "float32"
     # §Perf hillclimb knob: shard_map row-sharded lookup (models/embedding
     # tp_multifeature_bag) instead of GSPMD take() over the sharded table
     tp_lookup: bool = False
     sharding_overrides: Tuple[Tuple[str, Optional[str]], ...] = ()
+
+    @property
+    def hots(self) -> Tuple[int, ...]:
+        """Each feature's bag size."""
+        return self.hot_sizes or (self.multi_hot,) * self.n_sparse
+
+    @property
+    def packed(self) -> bool:
+        """Whether the tables lie one after another in one (sum(rows), D)
+        leaf, looked up by flat (B, sum(hots)) ids: where tables or bags
+        differ in size. Otherwise they are stacked (n_sparse, rows, D) and
+        looked up by (B, n_sparse, hot) ids."""
+        return len(set(self.vocab_sizes)) > 1 or len(set(self.hots)) > 1
 
     def replace(self, **kw):
         return dataclasses.replace(self, **kw)

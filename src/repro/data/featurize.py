@@ -36,7 +36,7 @@ from __future__ import annotations
 import os
 import time
 from dataclasses import dataclass
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -52,8 +52,9 @@ _P3 = np.uint32(3266489917)
 # pure record ops (deterministic, golden-tested)
 # ---------------------------------------------------------------------------
 
-def hash_ids(raw_ids, vocab: int) -> np.ndarray:
-    """xxhash-style categorical hash: raw ids -> table rows in [0, vocab).
+def hash_ids(raw_ids, vocab) -> np.ndarray:
+    """xxhash-style categorical hash: raw ids -> table rows in [0, vocab)
+    (`vocab` a count, or one per id broadcast over the trailing axes).
 
     Pure integer avalanche (shift-xor-multiply finalizer) over the low 32
     bits — deterministic across processes, platforms, and RNG seeds (it
@@ -66,7 +67,7 @@ def hash_ids(raw_ids, vocab: int) -> np.ndarray:
     x = x ^ (x >> np.uint32(13))
     x = x * _P3
     x = x ^ (x >> np.uint32(16))
-    return (x % np.uint32(vocab)).astype(np.int32)
+    return (x % np.asarray(vocab, np.uint32)).astype(np.int32)
 
 
 def pool_pad(ids, lengths, hot: int) -> np.ndarray:
@@ -104,7 +105,14 @@ class RecordSpec:
     """Shape config for the synthetic click records flowing through a
     real-work pipeline (must match the consuming model's batch shape:
     `sparse_ids` (batch, n_sparse, hot) int32, `dense` (batch, n_dense)
-    f32, `label` (batch,) f32)."""
+    f32, `label` (batch,) f32).
+
+    With per-feature `hots` (and `vocabs`, one table size each) the
+    records are fixed-size multi-hot bags, as in the MLPerf multi-hot
+    Criteo data: feature f's raw list holds exactly hots[f] ids, each
+    hashed modulo vocabs[f], and `sparse_ids` is flat (batch, sum(hots)),
+    feature f's bag in its hots[f] columns; `vocab`, `k_raw` and `hot`
+    are then unused."""
     batch: int = 512
     n_sparse: int = 12
     n_dense: int = 13
@@ -112,6 +120,8 @@ class RecordSpec:
     k_raw: int = 8          # raw multi-value list width (pre-pooling)
     hot: int = 4            # pooled bag size the model consumes
     seed: int = 0           # planted-signal weights (labels learnable)
+    vocabs: Tuple[int, ...] = ()
+    hots: Tuple[int, ...] = ()
 
 
 def raw_block(rng: np.random.RandomState, rs: RecordSpec) -> dict:
@@ -121,15 +131,21 @@ def raw_block(rng: np.random.RandomState, rs: RecordSpec) -> dict:
     w_dense = w_rng.randn(rs.n_dense) * 0.5
     w_sparse = w_rng.randn(rs.n_sparse) * 0.3
     n = rs.batch
-    raw_ids = rng.randint(0, 1 << 31, size=(n, rs.n_sparse, rs.k_raw),
-                          dtype=np.int64)
-    lengths = rng.randint(1, rs.k_raw + 1,
-                          size=(n, rs.n_sparse)).astype(np.int32)
+    if rs.hots:                 # fixed bags: each list holds its bag size
+        raw_ids = rng.randint(0, 1 << 31, size=(n, sum(rs.hots)),
+                              dtype=np.int64)
+        heads = raw_ids[:, np.cumsum((0,) + rs.hots[:-1])]
+        lengths = {}
+    else:
+        raw_ids = rng.randint(0, 1 << 31, size=(n, rs.n_sparse, rs.k_raw),
+                              dtype=np.int64)
+        heads = raw_ids[:, :, 0]
+        lengths = {"lengths": rng.randint(1, rs.k_raw + 1,
+                                          size=(n, rs.n_sparse)).astype(np.int32)}
     dense_raw = rng.lognormal(0.0, 1.0, size=(n, rs.n_dense))
-    logit = dense_raw @ w_dense * 0.1 \
-        + ((raw_ids[:, :, 0] % 97) / 97.0 - 0.5) @ w_sparse
+    logit = dense_raw @ w_dense * 0.1 + ((heads % 97) / 97.0 - 0.5) @ w_sparse
     label = (rng.rand(n) < 1.0 / (1.0 + np.exp(-logit))).astype(np.float32)
-    return {"raw_ids": raw_ids, "lengths": lengths,
+    return {"raw_ids": raw_ids, **lengths,
             "dense_raw": dense_raw.astype(np.float32), "label": label}
 
 
@@ -140,10 +156,14 @@ def shuffle_block(block: dict, rng: np.random.RandomState) -> dict:
 
 
 def featurize_block(block: dict, rs: RecordSpec) -> dict:
-    """The feature UDF: hash raw ids, pool/pad multi-value lists, and
-    normalize dense features — raw block in, model-ready block out."""
-    hashed = hash_ids(block["raw_ids"], rs.vocab)
-    sparse = pool_pad(hashed, block["lengths"], rs.hot)
+    """The feature UDF: hash raw ids, pool/pad multi-value lists (fixed
+    bags: hash each id modulo its own table's rows), and normalize dense
+    features — raw block in, model-ready block out."""
+    if rs.hots:                 # fixed-size bags: no pooling, no padding
+        sparse = hash_ids(block["raw_ids"], np.repeat(rs.vocabs, rs.hots))
+    else:
+        hashed = hash_ids(block["raw_ids"], rs.vocab)
+        sparse = pool_pad(hashed, block["lengths"], rs.hot)
     return {"sparse_ids": sparse,
             "dense": dense_transform(block["dense_raw"]),
             "label": block["label"]}

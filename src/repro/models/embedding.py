@@ -8,7 +8,9 @@ mesh axis (the paper's "hybrid parallelism [49]" layout for DLRM).
 Two layouts are supported:
   - `stacked`: all n_sparse tables share one vocab size -> a single
     (n_sparse, rows, dim) array (best for sharding + the Pallas kernel path).
-  - `ragged`: per-feature vocab sizes -> one (rows_f, dim) array per feature.
+  - `packed`: per-feature vocab sizes and bag sizes -> every table one
+    after another in one (sum(rows), dim) array, looked up by flat ids
+    with per-column table offsets (`packed_multifeature_bag`; one device).
 The assigned recsys configs use `stacked` with hashed ids (hash % rows), the
 standard industrial trick (QR-hashing is the documented extension).
 """
@@ -131,6 +133,25 @@ def multifeature_bag(tables, ids, *, combiner: str = "sum"):
     out = jax.vmap(per_feature, in_axes=(0, 1), out_axes=1)(
         tables, ids)                # (B, F, D)
     return out
+
+
+def packed_multifeature_bag(table, ids, slot_offsets, hots):
+    """Packed-table multi-hot lookup, one fixed bag size per feature.
+
+    table: (sum(rows), D), every feature's table one after another;
+    ids: (B, sum(hots)) int32, feature f's bag in its hots[f] columns,
+    each id a row of its own table; slot_offsets: (sum(hots),) the first
+    row of each column's table in `table` (static). Returns (B, F, D),
+    each bag's sum.
+    """
+    with jax.named_scope("bag_lookup"):
+        rows = jnp.take(table, ids + jnp.asarray(slot_offsets, jnp.int32),
+                        axis=0)                      # (B, sum(hots), D)
+        out, start = [], 0
+        for h in hots:
+            out.append(jnp.sum(rows[:, start:start + h], axis=1))
+            start += h
+        return jnp.stack(out, axis=1)
 
 
 def tp_multifeature_bag(tables, ids, mesh, *, combiner: str = "sum"):

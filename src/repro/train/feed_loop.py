@@ -45,7 +45,13 @@ from repro.train.train_step import make_train_step
 def criteo_record(cfg: DLRMConfig, batch: int, seed: int = 0) -> RecordSpec:
     """Click records for `cfg`: Criteo's categorical fields are single
     values, so each raw list holds `multi_hot` ids and pools to
-    `multi_hot`."""
+    `multi_hot`; for packed tables, each feature's raw list holds exactly
+    its bag size, hashed into its own table (the multi-hot Criteo
+    variant's fixed bags)."""
+    if cfg.packed:
+        return RecordSpec(batch=batch, n_sparse=cfg.n_sparse,
+                          n_dense=cfg.n_dense, vocabs=tuple(cfg.vocab_sizes),
+                          hots=cfg.hots, seed=seed)
     return RecordSpec(batch=batch, n_sparse=cfg.n_sparse, n_dense=cfg.n_dense,
                       vocab=cfg.vocab_sizes[0], k_raw=cfg.multi_hot,
                       hot=cfg.multi_hot, seed=seed)
@@ -59,7 +65,10 @@ def warm_batch(record: RecordSpec) -> Dict[str, np.ndarray]:
 
 
 class DLRMTrainer:
-    """Parameters, optimizer state and train step of one DLRM config.
+    """Parameters, optimizer state and train step of one DLRM config
+    (dot or cross-network interaction, stacked or packed tables). Each
+    dispatched step adds the ids it looks up to the `ids_looked_up`
+    counter.
 
     With `mesh` None everything lives on the default device. With a mesh
     (axes "data" and "model"), the config's sharding rules place the
@@ -128,6 +137,7 @@ class DLRMTrainer:
     def step(self, i: int, batch: Dict[str, jax.Array]) -> Dict[str, Any]:
         self.params, self.opt_state, metrics = self._step(
             self.params, self.opt_state, i, batch)
+        tracing.count("ids_looked_up", batch["sparse_ids"].size)
         return metrics
 
     def warm_up(self, batch: Dict[str, jax.Array], iters: int = 3) -> float:
