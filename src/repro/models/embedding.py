@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def init_table(rng, rows: int, dim: int, dtype=jnp.float32, scale=None):
@@ -135,6 +136,12 @@ def multifeature_bag(tables, ids, *, combiner: str = "sum"):
     return out
 
 
+def slot_pad(n_slots: int) -> int:
+    """Pad columns `packed_multifeature_bag` adds to `n_slots` id columns
+    so that the slot axis fills whole (8, 128) float32 tiles."""
+    return -n_slots % 8
+
+
 def packed_multifeature_bag(table, ids, slot_offsets, hots):
     """Packed-table multi-hot lookup, one fixed bag size per feature.
 
@@ -143,15 +150,29 @@ def packed_multifeature_bag(table, ids, slot_offsets, hots):
     each id a row of its own table; slot_offsets: (sum(hots),) the first
     row of each column's table in `table` (static). Returns (B, F, D),
     each bag's sum.
+
+    The gathered rows stay (B, S', D) with S' = sum(hots) padded to whole
+    tiles (`slot_pad`), so neither the reshape of the flat gather nor its
+    transpose copies them; the pad columns read distinct valid rows, so
+    their (zero) gradients do not pile onto one row. All bags are summed
+    by one contraction with a static (F, S') 0/1 matrix, 1 where column
+    j holds an id of bag f and 0 on pad columns, at the highest
+    precision: each product is 1*x or 0*x, exact in float32, and the
+    rows' gradient is a single 1*g. The ids lie in their tables (the
+    featurizer's modulo), so the gather clips rather than masks.
     """
     with jax.named_scope("bag_lookup"):
-        rows = jnp.take(table, ids + jnp.asarray(slot_offsets, jnp.int32),
-                        axis=0)                      # (B, sum(hots), D)
-        out, start = [], 0
-        for h in hots:
-            out.append(jnp.sum(rows[:, start:start + h], axis=1))
-            start += h
-        return jnp.stack(out, axis=1)
+        b, s = ids.shape
+        flat = ids + jnp.asarray(slot_offsets, jnp.int32)
+        pad = slot_pad(s)
+        if pad:
+            fill = jnp.arange(b * pad, dtype=jnp.int32).reshape(b, pad)
+            flat = jnp.concatenate([flat, fill % table.shape[0]], axis=1)
+        rows = jnp.take(table, flat, axis=0, mode="clip")   # (B, S', D)
+        ends, col = np.cumsum(hots), np.arange(s + pad)
+        bags = (col >= (ends - np.asarray(hots))[:, None]) & (col < ends[:, None])
+        return jnp.einsum("fj,bjd->bfd", jnp.asarray(bags, table.dtype), rows,
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 def tp_multifeature_bag(tables, ids, mesh, *, combiner: str = "sum"):

@@ -38,6 +38,7 @@ from repro.data.featurize import RecordSpec, featurize_block, raw_block
 from repro.data.simulator import Allocation, MachineSpec
 from repro.launch.programs import abstract_init, shardings_for
 from repro.models import dlrm as dlrm_lib
+from repro.models.embedding import slot_pad
 from repro.train.optim import make_optimizer, opt_logical_axes
 from repro.train.train_step import make_train_step
 
@@ -68,7 +69,8 @@ class DLRMTrainer:
     """Parameters, optimizer state and train step of one DLRM config
     (dot or cross-network interaction, stacked or packed tables). Each
     dispatched step adds the ids it looks up to the `ids_looked_up`
-    counter.
+    counter and, for packed tables, the pad ids its lookup adds to fill
+    whole tiles (`embedding.slot_pad`) to `bag_pad_ids`.
 
     With `mesh` None everything lives on the default device. With a mesh
     (axes "data" and "model"), the config's sharding rules place the
@@ -82,6 +84,7 @@ class DLRMTrainer:
                  mesh: Optional[Mesh] = None) -> None:
         self.cfg = cfg
         self.mesh = mesh
+        self._pad_cols = slot_pad(sum(cfg.hots)) if cfg.packed else None
         self.opt = make_optimizer(optimizer, lr=lr)
         init = lambda: dlrm_lib.init_params(jax.random.PRNGKey(seed), cfg)
         abs_params, logical = abstract_init(init)
@@ -138,6 +141,9 @@ class DLRMTrainer:
         self.params, self.opt_state, metrics = self._step(
             self.params, self.opt_state, i, batch)
         tracing.count("ids_looked_up", batch["sparse_ids"].size)
+        if self._pad_cols is not None:
+            tracing.count("bag_pad_ids",
+                          batch["sparse_ids"].shape[0] * self._pad_cols)
         return metrics
 
     def warm_up(self, batch: Dict[str, jax.Array], iters: int = 3) -> float:

@@ -9,7 +9,9 @@ its formula. The CPU computes float32 products in float32, so the two
 differ only by the order of float32 sums: the tolerances below are a few
 ulps of float32 (eps 1.19e-7) grown over the sums they cross.
 """
+import json
 import math
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -128,8 +130,34 @@ def test_dcn_rowwise_adagrad_step_follows_the_reference():
     np.testing.assert_allclose(new_state["acc"]["tables"], acc, rtol=RTOL, atol=ATOL)
 
 
-@pytest.mark.parametrize("rows,hots", [(ROWS, HOTS), ((10, 10), (1, 4)),
-                                       ((5,), (6,)), ((9, 4, 2), (2, 2, 2))])
+def _cell(key):
+    """A key of the DCNv2 benchmark configuration."""
+    path = (pathlib.Path(__file__).resolve().parents[1]
+            / "benchmarks/chip/configs/dlrm-dcnv2-1chip.json")
+    return json.loads(path.read_text())[key]
+
+
+# the cell's own 26 bags (214 ids: 214 % 8 == 6) over tables of at most 97 rows
+CELL_HOTS = tuple(_cell("hot_sizes"))
+CELL_ROWS = tuple(min(r, 97) for r in _cell("rows"))
+# slot axes of 11, 5, 6, 6 and 214 ids are padded; 8, 16 and 24 are whole tiles
+BAGS = [(ROWS, HOTS), ((10, 10), (1, 4)), ((5,), (6,)), ((9, 4, 2), (2, 2, 2)),
+        ((9, 4, 2), (2, 2, 4)), ((10, 10), (3, 13)), ((7, 30, 2), (8, 8, 8)),
+        (CELL_ROWS, CELL_HOTS)]
+
+
+def _slice_bags(table, ids, slot_offsets, hots):
+    """The bags summed slice by slice: one gather, a sum over each bag's
+    columns, the sums stacked."""
+    rows = jnp.take(table, ids + jnp.asarray(slot_offsets), axis=0)
+    out, start = [], 0
+    for h in hots:
+        out.append(jnp.sum(rows[:, start:start + h], axis=1))
+        start += h
+    return jnp.stack(out, axis=1)
+
+
+@pytest.mark.parametrize("rows,hots", BAGS)
 def test_packed_bag_equals_per_table_bags(rows, hots):
     cfg = CFG.replace(n_sparse=len(rows), vocab_sizes=rows, hot_sizes=hots)
     table = jax.random.normal(jax.random.PRNGKey(0), (sum(rows), 8))
@@ -137,9 +165,30 @@ def test_packed_bag_equals_per_table_bags(rows, hots):
     got = packed_multifeature_bag(table, jnp.asarray(ids), dlrm.slot_offsets(cfg), hots)
     assert got.shape == (16, len(rows), 8)
     ends = np.cumsum(hots)
+    # the two sum a bag's N(0, 1) rows in different orders: a few float32
+    # ulps for bags of up to 8 ids, growing with the bag beyond that
+    tol = 1e-6 * max(1, max(hots) / 8)
     for f, (t, e, h) in enumerate(zip(_split(table, rows), ends, hots)):
         np.testing.assert_allclose(got[:, f], embedding_bag(t, ids[:, e - h:e]),
-                                   rtol=1e-6, atol=1e-6)
+                                   rtol=1e-6, atol=tol)
+
+
+@pytest.mark.parametrize("rows,hots", BAGS)
+def test_packed_bag_gradient_equals_the_slice_sums(rows, hots):
+    """The whole table's gradient of a weighted sum of the bags is the
+    slice-by-slice formulation's; the pad columns' rows get nothing."""
+    cfg = CFG.replace(n_sparse=len(rows), vocab_sizes=rows, hot_sizes=hots)
+    table = jax.random.normal(jax.random.PRNGKey(1), (sum(rows), 8))
+    ids = jnp.asarray(_batch(cfg, 48, 3)["sparse_ids"])
+    offsets = dlrm.slot_offsets(cfg)
+    w = jax.random.normal(jax.random.PRNGKey(2), (48, len(rows), 8))
+
+    def grad(bags):
+        return jax.grad(lambda t: jnp.sum(w * bags(t, ids, offsets, hots)))(table)
+    got, want = grad(packed_multifeature_bag), grad(_slice_bags)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    untouched = np.setdiff1d(np.arange(sum(rows)), np.asarray(ids + offsets))
+    assert not np.asarray(got)[untouched].any()
 
 
 @pytest.mark.parametrize("vocabs,hots", [(ROWS, HOTS), ((3, 1 << 19, 63), (1, 100, 27))])
@@ -188,7 +237,7 @@ def test_packed_tables_refuse_a_mesh():
 def test_train_on_feed_tiny_dcn():
     """The served loop with the DCN config: packed tables, fixed bags of
     unequal sizes from the pipeline's featurization, the cross network;
-    each dispatched step counts the ids it looked up."""
+    each dispatched step counts the ids it looked up and its pad ids."""
     trainer = DLRMTrainer(CFG, optimizer="rowwise_adagrad", lr=0.02)
     record = criteo_record(CFG, batch=64)
     assert record.hots == HOTS and record.vocabs == ROWS
@@ -201,6 +250,7 @@ def test_train_on_feed_tiny_dcn():
     assert len(run.losses) == 3
     assert all(math.isfinite(x) for x in run.losses)
     assert shapes == [(64, sum(HOTS))] * 3
-    # the warm-up's four steps and the three fed ones
+    # the warm-up's four steps and the three fed ones; 11 ids pad to 16
     assert run.spans["ids_looked_up"]["n"] == 7 * 64 * sum(HOTS)
+    assert run.spans["bag_pad_ids"]["n"] == 7 * 64 * 5
     assert run.teardown["all_joined"] is True
