@@ -1,13 +1,11 @@
-"""Benchmark entrypoint: one module per paper table/figure + roofline.
+"""Benchmark entrypoint: one module per paper table/figure.
 
-    PYTHONPATH=src python -m benchmarks.run [--only fig5|fig7|fig7_fleet|fig8|roofline|kernels]
+    PYTHONPATH=src python -m benchmarks.run [--only fig5|fig7|fig7_fleet|fig8]
 
   fig5   static throughput + OOM rates   (paper Fig. 5A/5B)
   fig7   rescale timelines + utilization (paper Fig. 7A-C, 2.05-2.29x)
   fig7_fleet  multi-trainer cluster co-tuning under churn (fleet plane)
   fig8   scaling drilldowns              (paper Fig. 8A-C)
-  roofline  §Roofline table from the dry-run artifacts
-  kernels   Pallas kernel micro-bench
 """
 from __future__ import annotations
 
@@ -18,12 +16,11 @@ import time
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
-                    help="fig5|fig7|fig7_fleet|fig8|roofline|kernels")
+                    help="fig5|fig7|fig7_fleet|fig8")
     args = ap.parse_args(argv)
     t0 = time.time()
 
-    from benchmarks import (fig5_static, fig7_fleet, fig7_rescale,
-                            fig8_scaling, kernels_bench, roofline)
+    from benchmarks import fig5_static, fig7_fleet, fig7_rescale, fig8_scaling
     ran = []
     if args.only in (None, "fig5"):
         fig5_static.run("criteo")
@@ -39,12 +36,6 @@ def main(argv=None):
     if args.only in (None, "fig8"):
         fig8_scaling.run()
         ran.append("fig8")
-    if args.only in (None, "roofline"):
-        roofline.run()
-        ran.append("roofline")
-    if args.only in (None, "kernels"):
-        kernels_bench.run()
-        ran.append("kernels")
     print(f"\nbenchmarks done ({', '.join(ran)}) in {time.time()-t0:.0f}s; "
           f"artifacts in experiments/bench/")
 

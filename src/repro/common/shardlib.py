@@ -3,16 +3,16 @@
 Every model annotates each parameter / activation dimension with a *logical*
 axis name ("vocab", "mlp", "batch", ...). A rules table maps logical names to
 physical mesh axes. This indirection is what lets one model definition run on
-the single-pod (data, model) mesh, the multi-pod (pod, data, model) mesh, and
-the 1-device CPU smoke-test mesh without touching model code — and it is the
-knob the §Perf hillclimbs turn.
+a (data, model) mesh and on one device without touching model code: the
+trainer takes its parameter shardings from here (`abstract_init`,
+`shardings_for`) and the model code its activation constraints (`ShardCtx`).
 """
 from __future__ import annotations
 
 from typing import Mapping, Optional, Sequence, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 Axis = Union[str, Sequence[str], None]
 
@@ -86,69 +86,6 @@ def _filter_axis(axis: Axis, mesh_axis_names: Sequence[str]) -> Axis:
     return kept if len(kept) > 1 else kept[0]
 
 
-def to_pspec(logical: Sequence[Optional[str]], rules: Mapping[str, Axis],
-             mesh_axis_names: Sequence[str]) -> P:
-    """Map a tuple of logical axis names to a PartitionSpec."""
-    out, used = [], set()
-    for name in logical:
-        if name is None:
-            out.append(None)
-            continue
-        axis = _filter_axis(rules.get(name), mesh_axis_names)
-        # A mesh axis may be used at most once per spec; later dims lose.
-        if axis is None:
-            out.append(None)
-        elif isinstance(axis, str):
-            if axis in used:
-                out.append(None)
-            else:
-                used.add(axis)
-                out.append(axis)
-        else:
-            kept = tuple(a for a in axis if a not in used)
-            if not kept:
-                out.append(None)
-            else:
-                used.update(kept)
-                out.append(kept if len(kept) > 1 else kept[0])
-    return P(*out)
-
-
-def pspec_tree(logical_tree, rules, mesh_axis_names):
-    """Same-structure pytree of PartitionSpecs from logical-axis tuples.
-
-    Leaves of `logical_tree` are tuples/lists of logical names (or None).
-    """
-    is_leaf = lambda x: isinstance(x, (tuple, list)) and all(
-        isinstance(e, (str, type(None))) for e in x
-    )
-    return jax.tree_util.tree_map(
-        lambda lg: to_pspec(lg, rules, mesh_axis_names), logical_tree,
-        is_leaf=is_leaf,
-    )
-
-
-def sharding_tree(pspec_tree_, mesh: Mesh):
-    return jax.tree_util.tree_map(
-        lambda spec: NamedSharding(mesh, spec), pspec_tree_,
-        is_leaf=lambda x: isinstance(x, P),
-    )
-
-
-def constrain(x, logical, rules, mesh_axis_names):
-    """with_sharding_constraint via logical names. No-op outside jit-mesh."""
-    spec = to_pspec(logical, rules, mesh_axis_names)
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError):
-        return x  # no mesh in scope (e.g. CPU smoke tests)
-
-
-# Logical dims that are "data-like": sharding them when the dim is smaller
-# than the mesh axis product would pad (batch=1 over 32 devices) — drop.
-DATA_DIMS = frozenset({"batch", "cache_batch", "candidates", "edges"})
-
-
 def axes_prod(axis: Axis, mesh) -> int:
     if axis is None:
         return 1
@@ -184,6 +121,32 @@ def sanitized_pspec(shape, logical, rules, mesh) -> P:
                 used.update(ax_tuple)
         out.append(axis)
     return P(*out)
+
+
+def abstract_init(init_thunk):
+    """eval_shape a params initializer returning (params, logical).
+
+    `logical` is static python data built during tracing; captured via a
+    side channel because eval_shape outputs must be arrays.
+    """
+    side = {}
+
+    def wrapper():
+        p, lg = init_thunk()
+        side["lg"] = lg
+        return p
+
+    return jax.eval_shape(wrapper), side["lg"]
+
+
+def shardings_for(abstract_tree, logical_tree, rules, mesh):
+    """NamedSharding tree for an abstract pytree (divisible-or-replicate,
+    one `sanitized_pspec` per leaf)."""
+    flat_abs, treedef = jax.tree_util.tree_flatten(abstract_tree)
+    flat_lg = treedef.flatten_up_to(logical_tree)
+    return jax.tree_util.tree_unflatten(treedef, [
+        NamedSharding(mesh, sanitized_pspec(leaf.shape, lg, rules, mesh))
+        for leaf, lg in zip(flat_abs, flat_lg)])
 
 
 class ShardCtx:

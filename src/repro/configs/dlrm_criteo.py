@@ -5,7 +5,6 @@
 26 * 8,388,608 * 128 ≈ 27.9B embedding params — the paper's "25B+
 parameters, most of which are in the embedding tables". Trained with
 hybrid parallelism (tables row-sharded over `model`), optimizer adagrad.
-Not one of the 40 assigned cells — an extra row in the dry-run matrix.
 
 `ONE_CHIP` is the deployment one chip of a 16-chip row-sharded job
 holds: every published width kept, one-hot lookups, and only the rows of
@@ -25,9 +24,7 @@ MODEL = DLRMConfig(
     bottom_mlp=(512, 256, 128),
     top_mlp=(1024, 1024, 512, 256, 1),
     multi_hot=1,
-    # bf16 tables + shard_map row-wise lookup; row-wise adagrad below. The
-    # paper-faithful fp32/adagrad/GSPMD baseline is variant 0 in
-    # benchmarks/perf_hillclimb.
+    # bf16 tables + shard_map row-wise lookup; row-wise adagrad below.
     param_dtype="bfloat16",
     tp_lookup=True,
     # 27.9B embedding params need every mesh axis:

@@ -1,7 +1,6 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 

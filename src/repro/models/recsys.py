@@ -5,7 +5,7 @@
   dien       [1809.03672]  GRU interest extractor + AUGRU interest evolution
   bert4rec   [1904.06690]  bidirectional self-attn over item sequences
 
-Input conventions (produced by data/synthetic.py and launch/input_specs):
+Input conventions (produced by data/synthetic.py):
   CTR models (wide-deep, xdeepfm):
      sparse_ids (B, n_sparse, multi_hot) int32 hashed, dense (B, n_dense) f32,
      label (B,) f32

@@ -36,7 +36,6 @@ from repro.common import shardlib, tracing
 from repro.configs.base import DLRMConfig
 from repro.data.featurize import RecordSpec, featurize_block, raw_block
 from repro.data.simulator import Allocation, MachineSpec
-from repro.launch.programs import abstract_init, shardings_for
 from repro.models import dlrm as dlrm_lib
 from repro.models.embedding import slot_pad
 from repro.train.optim import make_optimizer, opt_logical_axes
@@ -87,16 +86,16 @@ class DLRMTrainer:
         self._pad_cols = slot_pad(sum(cfg.hots)) if cfg.packed else None
         self.opt = make_optimizer(optimizer, lr=lr)
         init = lambda: dlrm_lib.init_params(jax.random.PRNGKey(seed), cfg)
-        abs_params, logical = abstract_init(init)
+        abs_params, logical = shardlib.abstract_init(init)
         if mesh is None:
             ctx = None
             p_shard = o_shard = self.batch_sharding = None
         else:
             rules = shardlib.make_rules(dict(cfg.sharding_overrides))
             ctx = shardlib.ShardCtx(mesh, rules)
-            p_shard = shardings_for(abs_params, logical, rules, mesh)
+            p_shard = shardlib.shardings_for(abs_params, logical, rules, mesh)
             abs_opt = jax.eval_shape(self.opt.init, abs_params)
-            o_shard = shardings_for(abs_opt, opt_logical_axes(
+            o_shard = shardlib.shardings_for(abs_opt, opt_logical_axes(
                 optimizer, logical, params=abs_params), rules, mesh)
             self.batch_sharding = NamedSharding(mesh, P("data"))
         self.params = jax.jit(lambda: init()[0], out_shardings=p_shard)()

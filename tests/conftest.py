@@ -1,7 +1,7 @@
 """Shared fixtures + the tsan-lite sanitizer plugin.
 
 NOTE: no XLA_FLAGS here — smoke tests and benches must see the real
-1-CPU device count (only launch/dryrun.py forces 512).
+1-CPU device count.
 
 REPRO_SANITIZE=1 installs `repro.lint.runtime`'s tracked lock factories
 and queue wrappers for the whole pytest session (CI's push-only

@@ -1,6 +1,6 @@
 """Per-architecture smoke tests: REDUCED config of the same family, one
 forward/train step on CPU, asserting output shapes + no NaNs (assignment
-requirement). The FULL configs are exercised only via the dry-run."""
+requirement). No test or benchmark cell runs the FULL configs."""
 import jax
 import jax.numpy as jnp
 import numpy as np

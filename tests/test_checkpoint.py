@@ -1,11 +1,9 @@
 """Checkpoint: roundtrip fidelity, atomicity, large-array sharding,
 latest-step resolution, InTune-state extras."""
-import json
 import os
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.train import checkpoint as ckpt
 

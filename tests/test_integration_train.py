@@ -4,7 +4,6 @@ grad-compression allreduce equivalence)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.configs.base import DLRMConfig, TransformerConfig
 from repro.data.synthetic import CriteoStream, TokenStream

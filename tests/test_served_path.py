@@ -125,6 +125,27 @@ def test_compile_cache_dir_rule(env_set, tmp_path):
         assert used == os.path.join(os.path.dirname(SRC), ".jax_cache")
 
 
+def test_served_path_imports_no_launch_or_lm_module():
+    """A fresh interpreter that imports the served path (the loop, the
+    runtime API, the controller, the feed, the proc executor and the
+    featurization) loads no pod dry-run and no LLM, MoE or GNN model
+    module."""
+    code = ("import sys\n"
+            "import repro.train.feed_loop, repro.api, repro.core.controller\n"
+            "import repro.data.device_feed, repro.data.proc_executor\n"
+            "import repro.data.featurize\n"
+            "print(' '.join(sorted(sys.modules)))\n")
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True, timeout=300)
+    loaded = out.stdout.strip().splitlines()[-1].split()
+    assert "repro.train.feed_loop" in loaded
+    barred = ("repro.models.transformer", "repro.models.moe",
+              "repro.models.gnn", "repro.models.layers")
+    assert [m for m in loaded if m.split(".")[:2] == ["repro", "launch"]
+            or m in barred] == []
+
+
 def test_train_on_feed_tiny_dlrm():
     """The shared loop end to end on the CPU: raw records through a tuned
     ProcessPipeline (forkserver workers: the trainer brought JAX up

@@ -4,7 +4,6 @@ accounting against hand-computed recursions, backlog-driven OOM, the
 process plane's rate-limited source pacing, the controller's
 staleness-AGING reward and its re-adaptation triggers, and (slow) the
 fig_stream sim acceptance run."""
-import math
 import multiprocessing as mp
 import sys
 import time
