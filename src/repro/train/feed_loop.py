@@ -13,8 +13,10 @@ widths on the chip, checked against a float32 reference):
   - `train_on_feed` runs the closed loop: the pipeline's worker
     processes featurize raw click records, batches cross onto the device
     through the metered feed, and InTune retunes the pipeline against
-    the measured feed telemetry while the device runs the step just
-    dispatched.
+    the measured feed telemetry while the device runs the steps
+    dispatched. The loop reads each step's loss one step late, so one
+    step stays queued behind the one running and the host's work between
+    dispatches does not idle the device.
 
 The pipeline is created only after the model is on the device, so its
 workers start from a clean forkserver (`proc_executor.default_context`),
@@ -182,16 +184,24 @@ def train_on_feed(trainer: DLRMTrainer, record: RecordSpec, *, steps: int,
                   on_step: Optional[Callable] = None,
                   log_every: int = 25) -> FeedRun:
     """Train `trainer` for `steps` steps on batches a tuned
-    ProcessPipeline featurizes from raw `record`s. After every
-    `tune_every`-th step is dispatched, and before its loss is read, the
-    tuner ticks (`Session.step`), so its host work overlaps the step on
-    the device.
+    ProcessPipeline featurizes from raw `record`s. Step i's loss is read
+    after step i+1 is dispatched, so one step stays queued on the device
+    while the host fetches, hooks and ticks. After every `tune_every`-th
+    step is dispatched the tuner ticks (`Session.step`), so its host work
+    overlaps the steps on the device. Counters: `steps_queued`, each
+    dispatch that found the step before still running;
+    `ticks_outlasting_step`, each tick at whose end the newest step had
+    ended.
 
     Hooks (all optional):
       restore(params, opt_state, tuner) -> (start, params, opt_state)
           resume from a checkpoint before the first step;
-      on_batch(i, params, batch)   before step i, with the device batch;
-      on_step(i, params, opt_state, tuner)   after step i's loss is read.
+      on_batch(i, params, batch)   before step i, with the device batch
+          and the parameters step i starts from;
+      on_step(i, params, opt_state, tuner)   after step i+1 is dispatched
+          (after the last step, for the last) and step i's loss is read,
+          with step i's own parameters and optimizer state. A hook that
+          raises leaves step i+1 queued: wait on `trainer.params`.
     """
     from repro.api import FeedBackend, Session
     from repro.core.controller import InTune
@@ -244,9 +254,32 @@ def train_on_feed(trainer: DLRMTrainer, record: RecordSpec, *, steps: int,
     losses: List[float] = []
     t0 = time.monotonic()
     t_first = None          # after the first loss: pipeline cold start over
+
+    def finish(i, metrics, params, opt_state):
+        """Read step i's loss, then run its hook with step i's own state."""
+        nonlocal t_first
+        with tracing.span("host_loop"):
+            with tracing.span("loss_read"):
+                losses.append(float(metrics["loss"]))
+            if t_first is None:
+                t_first = time.monotonic()
+        if log_every and i % log_every == 0:
+            rate = len(losses) * record.batch / (time.monotonic() - t0)
+            ticks = tracing.since(before).get("tuner_tick")
+            tick_ms = ticks["s"] / ticks["n"] * 1e3 if ticks else 0.0
+            print(f"step {i:4d} loss {losses[-1]:.4f} "
+                  f"({rate:,.0f} examples/s wall-clock) "
+                  f"workers {pipe.worker_counts()} "
+                  f"tick {tick_ms:.1f} ms")
+        if on_step is not None:
+            on_step(i, params, opt_state, tuner)
+
     # each iteration is tiled by the spans feed_wait, step_dispatch,
-    # every `tune_every` steps tuner_tick, and host_loop (loss_read); the
-    # caller's hooks run between them
+    # every `tune_every` steps tuner_tick, and host_loop (loss_read) of
+    # the step before; the caller's hooks run between them. One step
+    # stays queued behind the one running, so the host's work between
+    # two dispatches runs while the device works.
+    queued = None           # (i, metrics, params, opt_state) of step i
     try:
         for i in range(start, steps):
             with jax.profiler.StepTraceAnnotation("train", step_num=i):
@@ -255,32 +288,24 @@ def train_on_feed(trainer: DLRMTrainer, record: RecordSpec, *, steps: int,
                 if on_batch is not None:
                     on_batch(i, trainer.params, batch)
                 with tracing.span("step_dispatch"):
+                    if queued is not None and not queued[1]["loss"].is_ready():
+                        # step i-1 still runs: step i queues behind it
+                        tracing.count("steps_queued")
                     metrics = trainer.step(i, batch)
                 if i % tune_every == 0:
                     # the tick's host work runs while the device runs
-                    # step i; the window it measures holds the steps
-                    # through i-1, whose losses the loop has read
+                    # step i and any step still ahead of it
                     with tracing.span("tuner_tick"):
                         session.step()
                         if metrics["loss"].is_ready():
-                            # step i ended first: the device idled for
-                            # the rest of the tick
+                            # step i ended first: the queue ran dry and
+                            # the device idled for the rest of the tick
                             tracing.count("ticks_outlasting_step")
-                with tracing.span("host_loop"):
-                    with tracing.span("loss_read"):
-                        losses.append(float(metrics["loss"]))
-                    if t_first is None:
-                        t_first = time.monotonic()
-                if log_every and i % log_every == 0:
-                    rate = len(losses) * record.batch / (time.monotonic() - t0)
-                    ticks = tracing.since(before).get("tuner_tick")
-                    tick_ms = ticks["s"] / ticks["n"] * 1e3 if ticks else 0.0
-                    print(f"step {i:4d} loss {losses[-1]:.4f} "
-                          f"({rate:,.0f} examples/s wall-clock) "
-                          f"workers {pipe.worker_counts()} "
-                          f"tick {tick_ms:.1f} ms")
-                if on_step is not None:
-                    on_step(i, trainer.params, trainer.opt_state, tuner)
+                if queued is not None:
+                    finish(*queued)
+                queued = (i, metrics, trainer.params, trainer.opt_state)
+        if queued is not None:
+            finish(*queued)
         t_end = time.monotonic()
         workers = list(pipe.worker_counts())
     finally:

@@ -151,31 +151,39 @@ def test_profiler_trace_holds_the_spans_nested_on_one_host_line(traced_run):
     events = [(e.name, e.start_ns, e.start_ns + e.duration_ns) for e in loop[0]]
 
     def named(name):
-        return [(s, e) for n, s, e in events if n == name]
+        return sorted((s, e) for n, s, e in events if n == name)
 
     def inside(inner, outer):
         return all(any(os <= s and e <= oe for os, oe in named(outer))
                    for s, e in named(inner))
 
-    assert len(named("train")) == 5
+    trains = named("train")
+    assert len(trains) == 5
     for name in LOOP + ("tuner_tick",):
-        assert inside(name, "train"), name
+        got = named(name)
+        if name in ("host_loop", "loss_read"):
+            # the last step's loss is read once the loop has ended
+            assert got[-1][0] >= trains[-1][1], name
+            got = got[:-1]
+        assert all(any(ts <= s and e <= te for ts, te in trains)
+                   for s, e in got), name
     assert inside("loss_read", "host_loop")
     for name in ("tick_measure", "tick_observe", "tick_propose", "tick_apply"):
         assert len(named(name)) == 3 and inside(name, "tuner_tick"), name
     assert inside("pipe_stats", "tick_measure")
 
-    # a tick runs between its step's dispatch and that step's loss read
+    # a tick runs between its step's dispatch and that step's loss read,
+    # which comes after the next step's dispatch; step k's loss is the
+    # k-th read
     def within(name, lo, hi):
         return [(s, e) for s, e in named(name) if lo <= s and e <= hi]
 
-    ticks = named("tuner_tick")
-    assert len(ticks) == 3
-    for ts, te in ticks:
-        (lo, hi), = [(s, e) for s, e in named("train") if s <= ts and te <= e]
+    ticks, reads = named("tuner_tick"), named("loss_read")
+    assert len(ticks) == 3 and len(reads) == 5
+    for k, (ts, te) in zip((0, 2, 4), ticks):
+        (lo, hi), = [(s, e) for s, e in trains if s <= ts and te <= e]
         (_, dispatched), = within("step_dispatch", lo, hi)
-        (read, _), = within("loss_read", lo, hi)
-        assert dispatched <= ts and te <= read
+        assert dispatched <= ts and te <= reads[k][0]
     # the loop's four spans are siblings: none overlaps another
     tiles = sorted(s for name in LOOP[:3] + ("tuner_tick",) for s in named(name))
     assert all(a[1] <= b[0] for a, b in zip(tiles, tiles[1:]))
@@ -184,39 +192,60 @@ def test_profiler_trace_holds_the_spans_nested_on_one_host_line(traced_run):
     assert starts and all(dict(e.stats)["n"] == 1 for e in starts)
 
 
-def test_tick_runs_while_its_step_is_in_flight(monkeypatch):
-    """Each tick starts after `trainer.step(i)` returns and ends before
-    step i's loss becomes a float; `on_batch` and `on_step` keep their
-    places, and `on_step` sees step i's own parameters. A tick whose
-    step had already ended counts `ticks_outlasting_step` once: here
-    steps 0 and 4 have ended, and step 2 reports itself still running."""
-    from repro.api import Session
-    events, params_after = [], {}
+class _Loss:
+    """Step i's loss, recording when the loop reads it; a step in
+    `running` reports itself still running."""
 
-    class Loss:
-        """Step i's loss, recording when the loop reads it."""
+    def __init__(self, i, arr, events, running):
+        self.i, self.arr, self.events, self.running = i, arr, events, running
 
-        def __init__(self, i, arr):
-            self.i, self.arr = i, arr
+    def is_ready(self):
+        return self.i not in self.running and self.arr.is_ready()
 
-        def is_ready(self):
-            return self.i != 2 and self.arr.is_ready()
+    def __float__(self):
+        self.events.append(("loss_read", self.i))
+        return float(self.arr)
 
-        def __float__(self):
-            events.append(("loss_read", self.i))
-            return float(self.arr)
 
-    trainer = DLRMTrainer(TINY, optimizer="rowwise_adagrad", lr=0.02)
-    orig_step, orig_tick = trainer.step, Session.step
+def _record_steps(trainer, events, running=()):
+    """Wrap `trainer.step`: each fed step (the warm-up's steps, which come
+    before the first batch's event, pass through) ends on the device
+    before it returns, appends ("step", i) to `events` and returns a
+    `_Loss`. Returns {i: the parameters step i left}."""
+    orig, params_after = trainer.step, {}
 
     def step(i, batch):
-        m = orig_step(i, batch)
-        if not events:                  # the warm-up's steps
+        m = orig(i, batch)
+        if not events:
             return m
         jax.block_until_ready(m["loss"])
         params_after[i] = trainer.params
         events.append(("step", i))
-        return {**m, "loss": Loss(i, m["loss"])}
+        return {**m, "loss": _Loss(i, m["loss"], events, running)}
+
+    trainer.step = step
+    return params_after
+
+
+def _train(trainer, events, steps=5, **hooks):
+    return train_on_feed(trainer, criteo_record(TINY, batch=64), steps=steps,
+                         tune_every=2, machine=MachineSpec(n_cpus=2, mem_mb=4096),
+                         on_batch=lambda i, p, b: events.append(("batch", i)),
+                         log_every=0, **hooks)
+
+
+def test_tick_runs_while_its_step_is_in_flight(monkeypatch):
+    """Each tick starts after `trainer.step(i)` returns and ends before
+    step i's loss becomes a float, which happens after step i+1 is
+    dispatched; `on_batch` keeps its place, `on_step(i)` follows step
+    i's loss read and sees step i's own parameters. A tick at whose end
+    the newest step had ended counts `ticks_outlasting_step` once: here
+    steps 0 and 4 have ended, and step 2 reports itself still running."""
+    from repro.api import Session
+    events = []
+    trainer = DLRMTrainer(TINY, optimizer="rowwise_adagrad", lr=0.02)
+    params_after = _record_steps(trainer, events, running={2})
+    orig_tick = Session.step
 
     def tick(self, *args, **kwargs):
         events.append(("tick", "start"))
@@ -228,21 +257,75 @@ def test_tick_runs_while_its_step_is_in_flight(monkeypatch):
         assert params is params_after[i]
         events.append(("on_step", i))
 
-    trainer.step = step
     monkeypatch.setattr(Session, "step", tick)
-    run = train_on_feed(trainer, criteo_record(TINY, batch=64), steps=5,
-                        tune_every=2, machine=MachineSpec(n_cpus=2, mem_mb=4096),
-                        on_batch=lambda i, p, b: events.append(("batch", i)),
-                        on_step=on_step, log_every=0)
+    run = _train(trainer, events, on_step=on_step)
     expected = []
     for i in range(5):
         expected += [("batch", i), ("step", i)]
         if i % 2 == 0:
             expected += [("tick", "start"), ("tick", "end")]
-        expected += [("loss_read", i), ("on_step", i)]
+        if i > 0:
+            expected += [("loss_read", i - 1), ("on_step", i - 1)]
+    expected += [("loss_read", 4), ("on_step", 4)]
     assert events == expected
     assert len(run.losses) == 5 and all(map(math.isfinite, run.losses))
     assert run.spans["ticks_outlasting_step"] == {"n": 2, "s": 0.0}
+
+
+def test_steps_queued_counts_dispatches_behind_a_running_step():
+    """`steps_queued` counts each dispatch whose step before reported
+    itself still running: steps 0, 3 and 5 do, and only the dispatches
+    of steps 1 and 4 come after them."""
+    events = []
+    trainer = DLRMTrainer(TINY, optimizer="rowwise_adagrad", lr=0.02)
+    _record_steps(trainer, events, running={0, 3, 5})
+    run = _train(trainer, events, steps=6)
+    assert [e for e in events if e[0] == "step"] == [("step", i) for i in range(6)]
+    assert run.spans["steps_queued"] == {"n": 2, "s": 0.0}
+    # the ticks after steps 2 and 4 found them ended; step 0 ran on
+    assert run.spans["ticks_outlasting_step"] == {"n": 2, "s": 0.0}
+
+
+def test_losses_are_each_steps_own_in_order():
+    """`FeedRun.losses` holds, in step order, the float of each loss array
+    the step returned, read with no wait added."""
+    started, returned = [], []
+    trainer = DLRMTrainer(TINY, optimizer="rowwise_adagrad", lr=0.02)
+    orig = trainer.step
+
+    def step(i, batch):
+        m = orig(i, batch)
+        if started:                     # not the warm-up's steps
+            returned.append(m["loss"])
+        return m
+
+    trainer.step = step
+    run = _train(trainer, started, steps=5)
+    assert len(returned) == 5
+    assert run.losses == [float(a) for a in returned]
+
+
+def test_a_raising_on_step_leaves_the_next_step_queued():
+    """A hook that raises in `on_step(k)` ends the loop with step k+1
+    dispatched, its loss unread and its parameters in `trainer.params`."""
+
+    class Stop(Exception):
+        pass
+
+    def on_step(i, params, opt_state, tuner):
+        events.append(("on_step", i))
+        if i == 2:
+            raise Stop
+
+    events = []
+    trainer = DLRMTrainer(TINY, optimizer="rowwise_adagrad", lr=0.02)
+    params_after = _record_steps(trainer, events)
+    with pytest.raises(Stop):
+        _train(trainer, events, on_step=on_step)
+    assert [e for e in events if e[0] == "step"] == [("step", i) for i in range(4)]
+    assert [e for e in events if e[0] in ("loss_read", "on_step")] == \
+        [(name, i) for i in range(3) for name in ("loss_read", "on_step")]
+    assert trainer.params is params_after[3]
 
 
 # --------------------------------------------------------- the step's scopes --
